@@ -43,7 +43,7 @@ _FUSION_CHECK = """
     for name in ("none", "onebit", "int8", "topk"):
         comp = None if name == "none" else get_compressor(name)
         fn = shard_map(build_exchange(comp, bucket_bytes), mesh=mesh,
-                       axis_names={"pod"}, in_specs=(P("pod"), P("pod")),
+                       in_specs=(P("pod"), P("pod")),
                        out_specs=(P("pod"), P("pod")), check_vma=False)
         with set_mesh(mesh):
             c = jax.jit(fn).lower(g, g).compile()
@@ -56,17 +56,25 @@ _FUSION_CHECK = """
 """
 
 
-def check_fusion():
-    """Lower the bucketed exchange on 4 forced host devices (subprocess:
-    this process must keep the single real device) and emit the
-    collective-count / wire-byte evidence."""
+def _run_on_host_devices(script: str):
+    """Run ``script`` in a child on 4 forced host CPU devices.  These are
+    lowering checks: the child never needs the chip, and this process has
+    already touched JAX (benchmarks/common.py), so it may hold the chip."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4")
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    out = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(_FUSION_CHECK)],
+    env["JAX_PLATFORMS"] = "cpu"
+    return subprocess.run(
+        [sys.executable, "-c", textwrap.dedent(script)],
         capture_output=True, text=True, env=env, timeout=560)
+
+
+def check_fusion():
+    """Lower the bucketed exchange on 4 forced host devices (subprocess:
+    this process must keep the single real device) and emit the
+    collective-count / wire-byte evidence."""
+    out = _run_on_host_devices(_FUSION_CHECK)
     if out.returncode != 0:
         emit("roofline/fusion", 0.0, "error=" + out.stderr[-200:].replace(
             "\n", " ").replace(",", ";"))
@@ -118,7 +126,7 @@ _ZERO1_CHECK = """
 
     rep = jax.tree.map(lambda _: P(), params)
     ssp = jax.tree.map(lambda _: P("pod"), opt_state)
-    fn = shard_map(body, mesh=mesh, axis_names={"pod"},
+    fn = shard_map(body, mesh=mesh,
                    in_specs=(rep, rep, ssp), out_specs=(rep, ssp),
                    check_vma=False)
     with set_mesh(mesh):
@@ -142,13 +150,7 @@ def check_zero1():
     """Lower the partitioned (ZeRO-1) exchange on 4 forced host devices and
     emit the reduce-scatter/all-gather counts + the ~W per-worker
     optimizer-state shrink."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=4")
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    out = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(_ZERO1_CHECK)],
-        capture_output=True, text=True, env=env, timeout=560)
+    out = _run_on_host_devices(_ZERO1_CHECK)
     if out.returncode != 0:
         emit("roofline/zero1", 0.0, "error=" + out.stderr[-200:].replace(
             "\n", " ").replace(",", ";"))
@@ -207,7 +209,7 @@ _PRECISION_CHECK = """
 
         rep = jax.tree.map(lambda _: P(), params)
         ssp = jax.tree.map(lambda _: P("pod"), opt_state)
-        fn = shard_map(body, mesh=mesh, axis_names={"pod"},
+        fn = shard_map(body, mesh=mesh,
                        in_specs=(rep, rep, ssp), out_specs=(rep, ssp),
                        check_vma=False)
         with set_mesh(mesh):
@@ -232,13 +234,7 @@ def check_precision():
     """Lower the ZeRO-1 exchange under the f32 and bf16 policies and emit
     the wire-shrink evidence: the bf16 reduce-scatter/all-gather ship ~2x
     fewer bytes and no f32 reduce-scatter survives in the HLO."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=4")
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    out = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(_PRECISION_CHECK)],
-        capture_output=True, text=True, env=env, timeout=560)
+    out = _run_on_host_devices(_PRECISION_CHECK)
     if out.returncode != 0:
         emit("roofline/precision", 0.0, "error=" + out.stderr[-200:].replace(
             "\n", " ").replace(",", ";"))
@@ -321,7 +317,7 @@ _ACCUM_CHECK = """
             specs = (jax.tree.map(lambda _: P(), params), P(None, "pod"), ssp)
             outs = (jax.tree.map(lambda _: P(), params), ssp)
             args = (params, jnp.zeros((k, PODS * B, 16)), opt_state)
-        fn = shard_map(body, mesh=mesh, axis_names={"pod"},
+        fn = shard_map(body, mesh=mesh,
                        in_specs=specs, out_specs=outs, check_vma=False)
         with set_mesh(mesh):
             c = jax.jit(fn).lower(*args).compile()
@@ -345,13 +341,7 @@ def check_accum():
     SAMPLE shrink by exactly accum_steps while the step HLO still carries
     one exchange's worth of collectives (≤ n_buckets, the fused-Fabric
     bound) per boundary — the scan body is collective-free."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=4")
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    out = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(_ACCUM_CHECK)],
-        capture_output=True, text=True, env=env, timeout=560)
+    out = _run_on_host_devices(_ACCUM_CHECK)
     if out.returncode != 0:
         emit("roofline/accum", 0.0, "error=" + out.stderr[-200:].replace(
             "\n", " ").replace(",", ";"))

@@ -134,7 +134,7 @@ def exchange_artifacts(params, strategy_name: str, precision: str,
         return p2, s2, c2
 
     crep = jax.tree.map(lambda _: P(), cstate)
-    fn = shard_map(body, mesh=mesh, axis_names={"pod"},
+    fn = shard_map(body, mesh=mesh,
                    in_specs=(p_spec, rep, opt_spec, crep, P()),
                    out_specs=(p_spec, opt_spec, crep),
                    check_vma=False)
@@ -261,7 +261,7 @@ def tp_artifacts(precision: str, tp_degree: int = TP_DEGREE) -> dict:
 
     mesh = make_mesh((tp_degree,), ("model",))
     spec = jax.tree.map(lambda _: P("model"), shards)
-    fn = shard_map(rank_step, mesh=mesh, axis_names={"model"},
+    fn = shard_map(rank_step, mesh=mesh,
                    in_specs=(spec,), out_specs=(P(), spec),
                    check_vma=False)
     with set_mesh(mesh):

@@ -204,6 +204,16 @@ class ModelConfig:
         return replace(self, sliding_window=window, global_every=None,
                        name=self.name + "-swa")
 
+    def with_depth(self, num_layers: int) -> "ModelConfig":
+        """Depth-only cut: every width as published, ``num_layers`` layers
+        (a whole number of super-blocks, at most the published depth)."""
+        period = len(self.superblock()[0])
+        if not 0 < num_layers <= self.num_layers or num_layers % period:
+            raise ValueError(
+                f"{self.name}: num_layers must be a multiple of {period} "
+                f"in [1, {self.num_layers}], got {num_layers}")
+        return replace(self, num_layers=num_layers)
+
     def reduced(self) -> "ModelConfig":
         """Smoke-test variant: ≤2 super-blocks, d_model ≤ 512, ≤4 experts."""
         specs, _ = self.superblock()

@@ -151,12 +151,20 @@ def topk_compressor(ratio: float = 0.01, block: int = 1024) -> Compressor:
 # ---------------------------------------------------------------------------
 # fused kernel encode+error-feedback rounds (the production Fabric path)
 # ---------------------------------------------------------------------------
-def _kernel_rows(rows: int) -> int:
-    """rows_per_step for the block-row kernels: interpret mode unrolls the
-    Pallas grid at trace time, so cap the grid at ~64 steps while keeping
-    the (8, 128) sublane alignment."""
-    per_step = -(-rows // 64)  # ceil: grid ≤ 64
-    return max(8, -(-per_step // 8) * 8)  # round up to sublane multiple
+def _kernel_rows(rows: int, block: int) -> int:
+    """rows_per_step for the block-row kernels, a multiple of the (8, 128)
+    sublane tile.  Compiled, a step holds about 1 MiB of f32 per input
+    block, so the double-buffered blocks stay well inside the scoped VMEM.
+    Interpret mode unrolls the Pallas grid at trace time, so there the
+    grid is capped at ~64 steps instead."""
+    from repro.kernels.ops import default_interpret
+
+    def up(x):
+        return max(8, -(-x // 8) * 8)
+
+    if default_interpret():
+        return up(-(-rows // 64))  # ceil: grid ≤ 64
+    return min(up(rows), up((1 << 18) // block))
 
 
 def _fold_blocks(g, r, block: int):
@@ -188,7 +196,7 @@ def _fused_onebit(block: int):
         lead, n = g.shape[:-1], g.shape[-1]
         gb, rb, nb, pad = _fold_blocks(g, r, block)
         packed, scale, newr = ops.onebit_quant_packed(
-            gb, rb, rows_per_step=_kernel_rows(gb.shape[0]))
+            gb, rb, rows_per_step=_kernel_rows(gb.shape[0], block))
         arrs = [packed.reshape(lead + (nb * (block // 8),)),
                 scale.reshape(lead + (nb, 1))]
 
@@ -208,7 +216,7 @@ def _fused_topk(k: int, block: int):
         lead, n = g.shape[:-1], g.shape[-1]
         gb, rb, nb, pad = _fold_blocks(g, r, block)
         vals, idx, newr = ops.topk_encode_ef(
-            gb, rb, k, rows_per_step=_kernel_rows(gb.shape[0]))
+            gb, rb, k, rows_per_step=_kernel_rows(gb.shape[0], block))
         arrs = [vals.reshape(lead + (nb, k)),
                 idx.astype(jnp.uint16).reshape(lead + (nb, k))]
 
@@ -320,10 +328,23 @@ def pack_signs(sign_int8):
 
 
 def unpack_signs(packed, n):
-    weights = jnp.asarray([1, 2, 4, 8, 16, 32, 64, 128], jnp.uint8)
-    bits = (packed[:, None] & weights) > 0
-    sign = jnp.where(bits.reshape(-1)[:n], 1, -1).astype(jnp.int8)
-    return sign
+    """Inverse of ``pack_signs``: the first ``n`` signs as int8 ±1.
+
+    Bytes go in rows of 32.  The eight bit planes of a row are laid side
+    by side (column 32·k + b holds bit k of byte b), and one 0/1
+    permutation matmul moves column 32·k + b to 8·b + k, the wire's order.
+    Every output is a single 0/1 product, so the matmul is exact at any
+    precision.  On a TPU this keeps every array lane-dense: expanding the
+    bits along a minor axis of 8 made XLA materialize a 16×-padded copy of
+    the decoded bucket (14 GiB for qwen2-1.5b's embedding at W=2)."""
+    rows = jnp.pad(packed, (0, (-packed.shape[0]) % 32)).reshape(-1, 32)
+    planes = jnp.concatenate([(rows >> k) & 1 for k in range(8)], axis=-1)
+    src = lax.broadcasted_iota(jnp.int32, (256, 256), 0)
+    dst = lax.broadcasted_iota(jnp.int32, (256, 256), 1)
+    perm = ((src % 32) * 8 + src // 32 == dst).astype(jnp.bfloat16)
+    bits = jnp.dot(planes.astype(jnp.bfloat16), perm,
+                   preferred_element_type=jnp.float32)
+    return jnp.where(bits.reshape(-1)[:n] > 0, 1, -1).astype(jnp.int8)
 
 
 # ---------------------------------------------------------------------------

@@ -1,96 +1,52 @@
-"""Version-tolerant spellings of the jax mesh / shard_map surface.
+"""The jax mesh / shard_map surface, in one spelling (jax 0.9).
 
-The repo is written against the modern API (``jax.shard_map``,
-``jax.set_mesh``, ``jax.sharding.AxisType``, ``get_abstract_mesh``); the
-pinned container may carry an older jax (0.4.x) where the same machinery
-lives under ``jax.experimental.shard_map`` and the mesh context manager.
-Every call site imports from here so the rest of the codebase stays
-single-spelling.
+Every call site imports from here, so a jax upgrade that moves one of these
+touches one file.
 """
 
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType  # noqa: F401
-except ImportError:  # pragma: no cover - exercised on old jax only
-    class AxisType:  # minimal stand-in: only .Auto is ever referenced
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where supported."""
-    try:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except TypeError:  # jax < 0.5: no axis_types kwarg
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    """jax.make_mesh with Auto axis types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def set_mesh(mesh):
     """Context manager activating ``mesh`` for sharding constraints."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh  # old jax: Mesh is itself a context manager
+    return jax.set_mesh(mesh)
 
 
 def get_abstract_mesh():
-    """The mesh of the current sharding context (None/empty when absent)."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src.mesh import thread_resources
-    return thread_resources.env.physical_mesh
+    """The mesh of the current sharding context (empty when absent)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def manual_axis_names(mesh):
-    """Axis names currently in Manual mode (inside a shard_map over them).
-
-    New jax records this on the abstract mesh's axis_types; old jax has no
-    axis_types, but any mesh axis bound in the tracing axis env is mapped
-    (old shard_map is full-manual over its mesh), which is what we need to
-    know to drop those axes from sharding constraints."""
-    types = getattr(mesh, "axis_types", None)
-    if types is not None:
-        return {n for n, t in zip(mesh.axis_names, types)
-                if "Manual" in str(t)}
-    try:
-        from jax._src.core import get_axis_env
-        bound = set(get_axis_env().axis_sizes)
-    except Exception:
-        return set()
-    return bound & set(mesh.axis_names)
+    """Axis names currently in Manual mode (inside a shard_map over them)."""
+    return {n for n, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == AxisType.Manual}
 
 
 def cost_analysis(compiled) -> dict:
-    """compiled.cost_analysis() as a flat dict (old jax returns a list)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """compiled.cost_analysis() as a flat dict."""
+    return dict(compiled.cost_analysis())
 
 
 def axis_size(name):
-    """Static size of a named mapped axis (inside shard_map/pmap)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)  # old jax: folded to a constant at trace
+    """Static size of a named mapped axis (inside shard_map)."""
+    return jax.lax.axis_size(name)
 
 
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-              check_vma=False):
-    """Map ``f`` over ``mesh``; manual over ``axis_names`` (all axes when
-    None), with replication checking off by default (both jax spellings)."""
-    if hasattr(jax, "shard_map"):  # jax >= 0.6
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    # Old jax: partial-auto shard_map trips an XLA SPMD partitioner CHECK,
-    # so lower full-manual — axes outside in_specs are simply replicated.
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
+    """Map ``f`` over ``mesh``, manual over EVERY mesh axis: an axis that
+    ``in_specs`` does not name is replicated into the body.  Partial-auto
+    lowering (``jax.shard_map(axis_names=...)``) trips an XLA SPMD
+    partitioner CHECK on a ("pod", "data", "model") mesh, so it is not
+    used."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

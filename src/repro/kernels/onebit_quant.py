@@ -88,7 +88,9 @@ def _onebit_packed_kernel(g_ref, r_ref, packed_ref, scale_ref, newr_ref,
     bits = (t >= 0).astype(jnp.float32)
     packed = jnp.dot(bits, _pack_matrix(block),
                      preferred_element_type=jnp.float32)
-    packed_ref[...] = packed.astype(jnp.uint8)
+    # byte values 0..255 are exact in f32; Mosaic casts to uint8 only
+    # from an integer type
+    packed_ref[...] = packed.astype(jnp.int32).astype(jnp.uint8)
     scale = jnp.mean(jnp.abs(t), axis=-1, keepdims=True)  # (rows, 1) f32
     scale_bf16 = scale.astype(jnp.bfloat16)
     scale_ref[...] = scale_bf16

@@ -8,14 +8,17 @@ single token per sequence — attending over that paged layout WITHOUT
 gathering the pages into a dense (B, S, KV, Dh) cache first.
 
 Mechanically it extends the ``flash_attention.py`` online-softmax
-pattern: grid = (batch, kv_heads, pages_per_seq) with f32 accumulators
-(acc, row-max m, row-sum l) in VMEM scratch persisting across the
-trailing (innermost, sequential) page dimension.  The page indirection
-rides ``pltpu.PrefetchScalarGridSpec``: the block table, context lengths
-and sliding window arrive as scalar-prefetch operands, so each k/v
-BlockSpec index map reads ``block_tables[b, j]`` and the pipeline DMAs
-exactly the physical page the sequence needs — the canonical TPU paged
-attention mechanism.  Dead pages (entirely past the context length, or
+pattern: grid = (batch, pages_per_seq) with f32 accumulators (acc, row-max
+m, row-sum l) per kv head in VMEM scratch, persisting across the trailing
+(innermost, sequential) page dimension.  A k/v block is one whole page,
+every kv head of it: the TPU lowering takes a block's last two dims
+(KV, Dh) only whole or in (8, 128) tiles, so a one-head block of a
+KV < 8 page is refused.  The kernel loops over the heads.  The page
+indirection rides ``pltpu.PrefetchScalarGridSpec``: the block table,
+context lengths and sliding window arrive as scalar-prefetch operands, so
+each k/v BlockSpec index map reads ``block_tables[b, j]`` and the pipeline
+DMAs exactly the physical page the sequence needs — the canonical TPU
+paged attention mechanism.  Dead pages (entirely past the context length, or
 entirely left of the sliding window) are skipped via ``@pl.when``, so
 decode compute is proportional to the LIVE context, not the allocated
 maximum.
@@ -45,8 +48,9 @@ def _paged_kernel(bt_ref, ctx_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, page_size: int, scale: float,
                   softcap: Optional[float]):
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
+    kv = q_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
@@ -63,33 +67,34 @@ def _paged_kernel(bt_ref, ctx_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale   # (G, Dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)        # (page, Dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)        # (page, Dh)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        jj = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = jnp.logical_and(jj <= pos, jj >= lo)
-        s = jnp.where(mask, s, NEG_INF)
+        for h in range(kv):
+            q = q_ref[0, h].astype(jnp.float32) * scale   # (G, Dh)
+            k = k_ref[0, :, h].astype(jnp.float32)        # (page, Dh)
+            v = v_ref[0, :, h].astype(jnp.float32)        # (page, Dh)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)
+            jj = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = jnp.logical_and(jj <= pos, jj >= lo)
+            s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+            m_prev = m_ref[h]
+            m_cur = jnp.max(s, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)
+            p = jnp.where(mask, p, 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(j == nj - 1)
     def _finalize():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -121,29 +126,29 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
     bt = block_tables.astype(jnp.int32)
     ctx = ctx_lens.astype(jnp.int32)
 
-    grid = (b, kv, mb)
+    grid = (b, mb)
     kernel = functools.partial(_paged_kernel, page_size=page_size,
                                scale=dh ** -0.5, softcap=softcap)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, g, dh),
-                         lambda b_, h_, j_, bt_, ctx_, win_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b_, h_, j_, bt_, ctx_, win_:
-                         (bt_[b_, j_], 0, h_, 0)),
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b_, h_, j_, bt_, ctx_, win_:
-                         (bt_[b_, j_], 0, h_, 0)),
+            pl.BlockSpec((1, kv, g, dh),
+                         lambda b_, j_, bt_, ctx_, win_: (b_, 0, 0, 0)),
+            pl.BlockSpec((1, page_size, kv, dh),
+                         lambda b_, j_, bt_, ctx_, win_:
+                         (bt_[b_, j_], 0, 0, 0)),
+            pl.BlockSpec((1, page_size, kv, dh),
+                         lambda b_, j_, bt_, ctx_, win_:
+                         (bt_[b_, j_], 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, g, dh),
-            lambda b_, h_, j_, bt_, ctx_, win_: (b_, h_, 0, 0)),
+            (1, kv, g, dh),
+            lambda b_, j_, bt_, ctx_, win_: (b_, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, dh), jnp.float32),   # acc
-            pltpu.VMEM((g, 1), jnp.float32),    # running max m
-            pltpu.VMEM((g, 1), jnp.float32),    # running sum l
+            pltpu.VMEM((kv, g, dh), jnp.float32),   # acc
+            pltpu.VMEM((kv, g, 1), jnp.float32),    # running max m
+            pltpu.VMEM((kv, g, 1), jnp.float32),    # running sum l
         ],
     )
     return pl.pallas_call(

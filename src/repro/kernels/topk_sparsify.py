@@ -29,27 +29,41 @@ def _resolve(interpret):
     return default_interpret()
 
 
-def _topk_kernel(x_ref, vals_ref, idx_ref, dense_ref, *, k: int, block: int):
-    x = x_ref[...]  # (rows, block)
-    mag = jnp.abs(x.astype(jnp.float32))
-    dense = jnp.zeros_like(x)
-    cols = jax.lax.broadcasted_iota(jnp.int32, mag.shape, 1)
+def _topk_rows(t, k: int, block: int):
+    """k iterations of (max, lowest-index, mask) over each row of the f32
+    block ``t``.  The k picks build up in (rows, k) carries and are stored
+    once, so no store lands at a lane offset that is not a multiple of 128.
+    Returns (vals (rows, k), idx (rows, k) int32, dense (rows, block))."""
+    rows = t.shape[0]
+    cols = jax.lax.broadcasted_iota(jnp.int32, t.shape, 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (rows, k), 1)
 
     def body(i, carry):
-        mag_c, dense_c = carry
-        m = jnp.max(mag_c, axis=-1, keepdims=True)  # (rows,1)
+        mag, dense, vals, idx = carry
+        m = jnp.max(mag, axis=-1, keepdims=True)  # (rows,1)
         # first column achieving the max
-        hit = mag_c == m
-        first = jnp.min(jnp.where(hit, cols, block), axis=-1, keepdims=True)
+        first = jnp.min(jnp.where(mag == m, cols, block), axis=-1,
+                        keepdims=True)
         sel = cols == first
-        vals_ref[:, i] = jnp.sum(jnp.where(sel, x, 0.0), axis=-1)
-        idx_ref[:, i] = first[:, 0]
-        dense_c = jnp.where(sel, x, dense_c)
-        mag_c = jnp.where(sel, NEG, mag_c)
-        return mag_c, dense_c
+        val = jnp.sum(jnp.where(sel, t, 0.0), axis=-1, keepdims=True)
+        vals = jnp.where(slot == i, val, vals)
+        idx = jnp.where(slot == i, first, idx)
+        dense = jnp.where(sel, t, dense)
+        mag = jnp.where(sel, NEG, mag)
+        return mag, dense, vals, idx
 
-    mag, dense = jax.lax.fori_loop(0, k, body, (mag, dense))
-    dense_ref[...] = dense
+    init = (jnp.abs(t), jnp.zeros_like(t), jnp.zeros((rows, k), jnp.float32),
+            jnp.zeros((rows, k), jnp.int32))
+    _, dense, vals, idx = jax.lax.fori_loop(0, k, body, init)
+    return vals, idx, dense
+
+
+def _topk_kernel(x_ref, vals_ref, idx_ref, dense_ref, *, k: int, block: int):
+    x = x_ref[...]  # (rows, block)
+    vals, idx, dense = _topk_rows(x.astype(jnp.float32), k, block)
+    vals_ref[...] = vals.astype(vals_ref.dtype)
+    idx_ref[...] = idx
+    dense_ref[...] = dense.astype(dense_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "rows_per_step", "interpret"))
@@ -85,27 +99,13 @@ def topk_sparsify(x, k: int, rows_per_step: int = 8,
 
 def _topk_ef_kernel(g_ref, r_ref, vals_ref, idx_ref, newr_ref,
                     *, k: int, block: int):
-    """Fused DGC round: t = g + r, block-local top-k of |t| (same
-    (max, lowest-index, mask) iteration as ``_topk_kernel``), and the
-    error-feedback residual t − dense(sent) — one VMEM pass."""
+    """Fused DGC round: t = g + r, block-local top-k of |t| (the same
+    iteration as ``_topk_kernel``), and the error-feedback residual
+    t − dense(sent) — one VMEM pass."""
     t = g_ref[...].astype(jnp.float32) + r_ref[...]
-    mag = jnp.abs(t)
-    dense = jnp.zeros_like(t)
-    cols = jax.lax.broadcasted_iota(jnp.int32, mag.shape, 1)
-
-    def body(i, carry):
-        mag_c, dense_c = carry
-        m = jnp.max(mag_c, axis=-1, keepdims=True)  # (rows,1)
-        hit = mag_c == m
-        first = jnp.min(jnp.where(hit, cols, block), axis=-1, keepdims=True)
-        sel = cols == first
-        vals_ref[:, i] = jnp.sum(jnp.where(sel, t, 0.0), axis=-1)
-        idx_ref[:, i] = first[:, 0]
-        dense_c = jnp.where(sel, t, dense_c)
-        mag_c = jnp.where(sel, NEG, mag_c)
-        return mag_c, dense_c
-
-    mag, dense = jax.lax.fori_loop(0, k, body, (mag, dense))
+    vals, idx, dense = _topk_rows(t, k, block)
+    vals_ref[...] = vals
+    idx_ref[...] = idx
     newr_ref[...] = t - dense
 
 

@@ -80,7 +80,7 @@ def lower_exchange(arch: str, compressor_name: str,
     comp = None if compressor_name == "none" else get_compressor(compressor_name)
     fn = build_exchange(comp, bucket_bytes)
     smapped = compat.shard_map(
-        fn, mesh=mesh, axis_names={"pod"},
+        fn, mesh=mesh,
         in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod")),
         check_vma=False)
     with compat.set_mesh(mesh):

@@ -1,14 +1,19 @@
 """End-to-end trainer CLI.
 
-Two modes mirroring DESIGN.md §3:
-  * replica-simulator mode (default on CPU): W model replicas under any
-    spectrum strategy + optional compression — the paper's experimental rig.
-  * sharded mode (--sharded): one global model under pjit on whatever
-    devices exist (data-parallel sync; the production path).
+W model replicas, stacked on one device, under any spectrum strategy and
+optional compression — the paper's experimental rig (DESIGN.md §3).
 
-Example:
+Two size cuts:
+  * ``--reduced``: the CPU-test preset, which cuts widths as well as depth;
+  * ``--num-layers N``: depth only.  Every width stays as published, which
+    is how a published model fits one chip (e.g. qwen2-1.5b at 4 of 28
+    layers).
+
+Examples:
   PYTHONPATH=src python -m repro.launch.train --arch gemma3-1b --reduced \
       --strategy gossip --workers 4 --steps 200 --compressor onebit
+  PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b \
+      --num-layers 4 --workers 1 --precision bf16 --steps 3
 """
 
 from __future__ import annotations
@@ -27,6 +32,7 @@ from repro.core.compression import get_compressor
 from repro.core.precision import POLICIES, apply_policy, get_policy
 from repro.core.strategies import REGISTRY, get_strategy
 from repro.data.pipeline import DataConfig, bayes_entropy, prefetch_batches
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.optim import adam, sgd, warmup_cosine
 from repro.train.loop import (init_train_state, make_loss_fn,
@@ -38,6 +44,10 @@ def build_argparser():
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--reduced", action="store_true",
                     help="use the smoke-scale variant of the arch")
+    ap.add_argument("--num-layers", type=int, default=None,
+                    help="cut depth only: keep every published width and "
+                         "run this many layers (a whole number of the "
+                         "arch's super-blocks)")
     ap.add_argument("--strategy", default="sync", choices=sorted(REGISTRY))
     ap.add_argument("--zero-stage", type=int, default=0,
                     choices=[0, 1, 2, 3],
@@ -152,6 +162,7 @@ def resume_auto(ckpt_dir, state, strategy, comm, policy, strategy_name):
 
 
 def main(argv=None):
+    use_compile_cache()
     args = build_argparser().parse_args(argv)
     try:
         cfg = get_config(args.arch)
@@ -161,6 +172,13 @@ def main(argv=None):
         raise SystemExit(2)
     if args.reduced:
         cfg = cfg.reduced()
+    published_layers = cfg.num_layers
+    if args.num_layers is not None:
+        try:
+            cfg = cfg.with_depth(args.num_layers)
+        except ValueError as e:
+            print(f"--num-layers: {e}", file=sys.stderr)
+            raise SystemExit(2)
     if args.zero_stage:
         if args.strategy not in ("sync", f"sync_zero{args.zero_stage}"):
             print(f"--zero-stage {args.zero_stage} conflicts with "
@@ -203,7 +221,8 @@ def main(argv=None):
     # microbatches of workers x batch_per_worker samples each, but ships
     # the wire bytes of ONE exchange
     samples_per_step = args.workers * args.batch_per_worker * args.accum_steps
-    print(f"arch={cfg.name} params={n_params:,} strategy={strategy.name} "
+    print(f"arch={cfg.name} layers={cfg.num_layers}/{published_layers} "
+          f"params={n_params:,} strategy={strategy.name} "
           f"precision={args.precision} workers={args.workers} "
           f"accum_steps={args.accum_steps} "
           f"global_batch={samples_per_step} "

@@ -536,7 +536,7 @@ def make_sharded_train_step(cfg, optimizer: Optimizer,
         and tests/test_accum.py).
 
         Like the ZeRO-1 production body above, the shard_map declares
-        replicated (P()) param specs, so on the old-jax full-manual
+        replicated (P()) param specs, so on the full-manual
         lowering (DESIGN.md §7) model-axis sharding is gathered at the
         body boundary — the same memory tradeoff the partition_grads path
         already makes; accum_steps=1 keeps the pjit auto-sharded path
@@ -564,7 +564,7 @@ def make_sharded_train_step(cfg, optimizer: Optimizer,
         batch_specs = jax.tree.map(lambda _: P(None, axes), batch)
         rep = jax.tree.map(lambda _: P(), params)
         return compat.shard_map(
-            per_shard, mesh=mesh, axis_names=set(axes),
+            per_shard, mesh=mesh,
             in_specs=(rep, batch_specs, P()),
             out_specs=(P(), rep), check_vma=False,
         )(params, batch, scale)
@@ -598,7 +598,7 @@ def make_sharded_train_step(cfg, optimizer: Optimizer,
         rep = jax.tree.map(lambda _: P(), params)
         rep_r = jax.tree.map(lambda _: P(), residual)
         return compat.shard_map(
-            per_pod, mesh=mesh, axis_names={"pod"},
+            per_pod, mesh=mesh,
             in_specs=(rep, batch_specs, rep_r, P()),
             out_specs=(P(), rep, rep_r), check_vma=False,
         )(params, batch, residual, scale)
@@ -670,7 +670,7 @@ def make_sharded_train_step(cfg, optimizer: Optimizer,
         rep = jax.tree.map(lambda _: P(), params)
         shard_specs = jax.tree.map(lambda _: P("pod"), opt_state)
         return compat.shard_map(
-            per_pod, mesh=mesh, axis_names={"pod"},
+            per_pod, mesh=mesh,
             in_specs=(rep, batch_specs, shard_specs, P(), P()),
             out_specs=(P(), rep, shard_specs, P()), check_vma=False,
         )(params, batch, opt_state, t, scale)
@@ -729,7 +729,7 @@ def make_sharded_train_step(cfg, optimizer: Optimizer,
         p_specs = jax.tree.map(lambda _: P("pod"), p_shards)
         o_specs = jax.tree.map(lambda _: P("pod"), opt_state)
         return compat.shard_map(
-            per_pod, mesh=mesh, axis_names={"pod"},
+            per_pod, mesh=mesh,
             in_specs=(p_specs, batch_specs, o_specs, P(), P()),
             out_specs=(P(), p_specs, o_specs, P()), check_vma=False,
         )(p_shards, batch, opt_state, t, scale)

@@ -40,6 +40,62 @@ def test_all_archs_registered():
 
 
 @pytest.mark.parametrize("arch", ALL_ARCHS)
+def test_with_depth_cuts_depth_only(arch):
+    """The depth cut keeps every field but num_layers, and takes one
+    whole super-block (the layer pattern's period) at the least."""
+    cfg = get_config(arch)
+    period = len(cfg.superblock()[0])
+    cut = cfg.with_depth(period)
+    assert cut.num_layers == period
+    assert dataclasses.replace(cut, num_layers=cfg.num_layers) == cfg
+    for bad in (0, cfg.num_layers + period) + ((period + 1,) if period > 1
+                                               else ()):
+        with pytest.raises(ValueError, match="num_layers"):
+            cfg.with_depth(bad)
+
+
+def test_trainer_num_layers_flag(capsys):
+    """--num-layers keeps the widths and prints the cut; a depth that is
+    not a whole number of super-blocks exits 2."""
+    from repro.launch.train import main
+    main(["--arch", "qwen2-1.5b", "--reduced", "--num-layers", "1",
+          "--workers", "1", "--steps", "1", "--seq-len", "8",
+          "--batch-per-worker", "1"])
+    assert "layers=1/2" in capsys.readouterr().out
+    with pytest.raises(SystemExit) as e:
+        main(["--arch", "jamba-1.5-large-398b", "--num-layers", "4",
+              "--steps", "1"])
+    assert e.value.code == 2
+    assert "multiple of 8" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code;
+    without it the cache goes to the fixed in-checkout directory."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    calls = []
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert compile_cache.use_compile_cache() == str(
+            compile_cache.DEFAULT_DIR)
+        assert calls == [("jax_compilation_cache_dir",
+                          str(compile_cache.DEFAULT_DIR))]
+        root = compile_cache.DEFAULT_DIR.parent
+        assert (root / "src" / "repro" / "launch").is_dir()
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+        assert compile_cache.use_compile_cache() == str(tmp_path / env_dir)
+        assert calls == []
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+@pytest.mark.parametrize("arch", ALL_ARCHS)
 def test_reduced_limits(arch):
     cfg = get_config(arch).reduced()
     specs, repeat = cfg.superblock()

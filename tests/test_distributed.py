@@ -140,7 +140,6 @@ def test_compressed_pod_exchange_lowers_and_reduces_wire():
         for name in ("none", "onebit"):
             comp = None if name == "none" else get_compressor(name)
             fn = shard_map(build_exchange(comp), mesh=mesh,
-                           axis_names={"pod"},
                            in_specs=(P("pod"), P("pod")),
                            out_specs=(P("pod"), P("pod")),
                            check_vma=False)

@@ -251,7 +251,6 @@ def test_exchange_lowering_is_fused_and_bytes_match():
         for name in ("none", "onebit", "int8"):
             comp = None if name == "none" else get_compressor(name)
             fn = shard_map(build_exchange(comp, bucket_bytes), mesh=mesh,
-                           axis_names={"pod"},
                            in_specs=(P("pod"), P("pod")),
                            out_specs=(P("pod"), P("pod")), check_vma=False)
             with set_mesh(mesh):

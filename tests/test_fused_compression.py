@@ -216,7 +216,7 @@ def test_shardcomm_fused_parity_bitwise():
                                  fused=fused)
                     m, nr, _ = fab.exchange(gg, rr, comp)
                     return m, nr
-                fn = shard_map(body, mesh=mesh, axis_names={"w"},
+                fn = shard_map(body, mesh=mesh,
                                in_specs=(P("w"), P("w")),
                                out_specs=(P("w"), P("w")), check_vma=False)
                 with set_mesh(mesh):

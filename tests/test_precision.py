@@ -380,7 +380,7 @@ def test_zero1_bf16_hlo_has_no_f32_reduce_scatter():
 
         rep = jax.tree.map(lambda _: P(), params)
         ssp = jax.tree.map(lambda _: P("pod"), opt_state)
-        fn = shard_map(body, mesh=mesh, axis_names={"pod"},
+        fn = shard_map(body, mesh=mesh,
                        in_specs=(rep, rep, ssp), out_specs=(rep, ssp),
                        check_vma=False)
         with set_mesh(mesh):
@@ -474,7 +474,7 @@ def test_dense_sync_bf16_hlo_has_no_f32_all_reduce():
             return p
 
         rep = jax.tree.map(lambda _: P(), params)
-        fn = shard_map(body, mesh=mesh, axis_names={"pod"},
+        fn = shard_map(body, mesh=mesh,
                        in_specs=(rep, rep), out_specs=rep, check_vma=False)
         with set_mesh(mesh):
             c = jax.jit(fn).lower(params, params).compile()

@@ -225,7 +225,7 @@ def test_zero1_lowering_is_partitioned():
 
         rep = jax.tree.map(lambda _: P(), params)
         ssp = jax.tree.map(lambda _: P("pod"), opt_state)
-        fn = shard_map(body, mesh=mesh, axis_names={"pod"},
+        fn = shard_map(body, mesh=mesh,
                        in_specs=(rep, rep, ssp), out_specs=(rep, ssp),
                        check_vma=False)
         with set_mesh(mesh):
@@ -306,7 +306,7 @@ def test_local_sgd_gating_drops_collective_bytes():
                     p2, _, _, _ = strat.update(p, g, {}, {}, _t, opt, comm)
                     return p2
                 rep = jax.tree.map(lambda _: P(), params)
-                fn = shard_map(body, mesh=mesh, axis_names={"pod"},
+                fn = shard_map(body, mesh=mesh,
                                in_specs=(rep, rep), out_specs=rep,
                                check_vma=False)
                 with set_mesh(mesh):

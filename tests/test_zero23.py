@@ -214,6 +214,7 @@ def test_sharded_step_lowers_zero23():
     out = _run("""
         import dataclasses, jax
         from repro.configs.base import get_config
+        from repro.core.jax_compat import set_mesh
         from repro.launch.mesh import make_mesh
         from repro.launch.specs import ShapeSpec, build_train_step
         cfg = dataclasses.replace(
@@ -223,7 +224,7 @@ def test_sharded_step_lowers_zero23():
         mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         shape = ShapeSpec("train_tiny", 16, 4, "train")
         for zs in (2, 3):
-            with mesh:
+            with set_mesh(mesh):
                 fn, sds, sh, donate = build_train_step(
                     cfg, shape, mesh, zero_stage=zs, accum_steps=2)
                 jax.jit(fn, in_shardings=sh,
