@@ -39,6 +39,7 @@ from repro.core.comm import Comm, ShardComm
 from repro.core.compression import (Compressor, _from_bytes,  # noqa: F401
                                     _narrow_wire, _pack, _to_bytes, _unpack,
                                     pack_signs, packed_nbytes, unpack_signs)
+from repro.core.scopes import EXCHANGE, scoped
 
 DEFAULT_BUCKET_BYTES = 4 << 20  # 4 MiB of f32 per bucket
 
@@ -211,7 +212,8 @@ class Fabric:
     Every public op issues at most ONE collective per bucket (and exactly
     one all-gather of packed bytes per bucket on the compressed ShardComm
     path).  Residual / DGC state stays param-shaped f32 trees, so existing
-    checkpoint and sharding-spec machinery is untouched."""
+    checkpoint and sharding-spec machinery is untouched.  Every public
+    exchange traces under the ``train.exchange`` scope (core/scopes.py)."""
 
     def __init__(self, comm: Comm, bucket_bytes: int = DEFAULT_BUCKET_BYTES,
                  wire_dtype=None, fused: bool = True):
@@ -285,9 +287,11 @@ class Fabric:
                                         axis=full.ndim - 1))
         return out
 
+    @scoped(EXCHANGE)
     def all_mean(self, tree):
         return self._reduce(tree, mean=True)
 
+    @scoped(EXCHANGE)
     def all_sum(self, tree):
         return self._reduce(tree, mean=False)
 
@@ -301,6 +305,7 @@ class Fabric:
         op = self.comm.all_mean if mean else self.comm.all_sum
         return lay.debucketize(op(self._wire_cast(gb)))
 
+    @scoped(EXCHANGE)
     def ppermute(self, tree, shift: int = 1):
         lay = self.layout(tree)
         if lay.n_leaves == 0:
@@ -515,6 +520,7 @@ class Fabric:
                 self.metrics(self.flat_bytes(play.layout) / 2.0))
 
     # -- fused exchanges ----------------------------------------------------
+    @scoped(EXCHANGE)
     def exchange(self, grads, residual=None, compressor=None, events=1.0):
         """Fused all-mean of ``grads`` with optional compression + error
         feedback.  Returns (mean_tree, new_residual_tree, metrics)."""
@@ -523,6 +529,7 @@ class Fabric:
                                          residual=residual,
                                          compressor=compressor, events=events)
 
+    @scoped(EXCHANGE)
     def exchange_accumulated(self, buckets, lay: BucketLayout, residual=None,
                              compressor=None, events=1.0):
         """The exchange of ``exchange`` starting from flat f32 buckets
@@ -546,6 +553,7 @@ class Fabric:
                 lay.debucketize(r_out, cast=False),
                 self.metrics(self.wire_bytes(lay, compressor), events))
 
+    @scoped(EXCHANGE)
     def exchange_dgc(self, grads, state, compressor, momentum: float = 0.9,
                      events=1.0):
         """Fused all-mean with DGC momentum correction (Lin et al. [54]):
@@ -581,6 +589,7 @@ class Fabric:
                 b, [(0, 0)] * (b.ndim - 1) + [(0, p - n)]))
         return out
 
+    @scoped(EXCHANGE)
     def shard_params(self, tree, play: Optional[PartitionedLayout] = None):
         """This worker's 1/W shard of each (replicated) flat f32 bucket —
         a local slice, no collective.  Feeds ``Optimizer.init``/``update``
@@ -590,6 +599,7 @@ class Fabric:
         buckets = self._pad_buckets(play.layout.bucketize(tree), play)
         return self.comm.shard_chunk(buckets)
 
+    @scoped(EXCHANGE)
     def exchange_partitioned(self, grads,
                              play: Optional[PartitionedLayout] = None,
                              events=1.0):
@@ -602,6 +612,7 @@ class Fabric:
         gb = self._pad_buckets(play.layout.bucketize(grads), play)
         return self.exchange_partitioned_accumulated(gb, play, events=events)
 
+    @scoped(EXCHANGE)
     def exchange_partitioned_accumulated(self, buckets,
                                          play: PartitionedLayout,
                                          events=1.0):
@@ -630,6 +641,7 @@ class Fabric:
                 shards = [s.astype(jnp.float32) for s in shards]
         return shards, self.metrics(self.flat_bytes(play.layout), events)
 
+    @scoped(EXCHANGE)
     def unpartition(self, shards, play: PartitionedLayout):
         """All-gather updated shards back into the full tree — one tiled
         all-gather per bucket (of ``wire_dtype`` buffers: the gathered
@@ -651,6 +663,7 @@ class Fabric:
                 for b, n in zip(full, play.layout.bucket_sizes)]
         return play.layout.debucketize(full)
 
+    @scoped(EXCHANGE)
     def compress(self, grads, residual, compressor):
         """Error-feedback compression WITHOUT a collective (for strategies
         that buffer/accumulate before communicating, e.g. SSP/Downpour).

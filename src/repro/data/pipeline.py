@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.scopes import DATA_BATCH
+
 
 @dataclass(frozen=True)
 class DataConfig:
@@ -102,9 +104,10 @@ def microbatch_stack(cfg: DataConfig, n_workers: int, opt_step,
 
 def global_batch(cfg: DataConfig, step: int, global_batch_size: int):
     """One flat global batch (production path); workers' shards concatenated."""
-    n = global_batch_size // cfg.batch_per_worker
-    ws = worker_batches(cfg, n, step)
-    return ws.reshape(global_batch_size, cfg.seq_len)
+    with jax.profiler.TraceAnnotation(DATA_BATCH):
+        n = global_batch_size // cfg.batch_per_worker
+        ws = worker_batches(cfg, n, step)
+        return ws.reshape(global_batch_size, cfg.seq_len)
 
 
 def prefetch_batches(cfg: DataConfig, n_workers: int, steps: int,
@@ -123,11 +126,12 @@ def prefetch_batches(cfg: DataConfig, n_workers: int, steps: int,
     q: deque = deque()
 
     def synth(t):
-        if accum_steps > 1:
-            b = microbatch_stack(cfg, n_workers, t, accum_steps)
-        else:
-            b = worker_batches(cfg, n_workers, t)
-        return jax.device_put(b)
+        with jax.profiler.TraceAnnotation(DATA_BATCH):
+            if accum_steps > 1:
+                b = microbatch_stack(cfg, n_workers, t, accum_steps)
+            else:
+                b = worker_batches(cfg, n_workers, t)
+            return jax.device_put(b)
 
     for t in range(steps):
         q.append((t, synth(t)))

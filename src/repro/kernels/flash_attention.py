@@ -124,5 +124,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = -1,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(b, h, lq_p, d)[:, :, :l]

@@ -68,5 +68,6 @@ def fused_adam(p, g, m, v, lr, t, b1=0.9, b2=0.999, eps=1e-8,
             jax.ShapeDtypeStruct((npad,), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_adam",
     )(consts, p, g, m, v)
     return p1[:n], m1[:n], v1[:n]

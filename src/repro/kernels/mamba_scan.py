@@ -87,5 +87,6 @@ def mamba_scan(u, delta, a, b, c, d_skip, d_block: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((d_block, n), jnp.float32)],
         interpret=interpret,
+        name="mamba_scan",
     )(u, delta, a, b, c, d_skip)
     return y[..., :d], hlast[:, :d]

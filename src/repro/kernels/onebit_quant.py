@@ -68,6 +68,7 @@ def onebit_quant(g, r, rows_per_step: int = 8,
             jax.ShapeDtypeStruct((nbp, block), jnp.float32),
         ],
         interpret=interpret,
+        name="onebit_quant",
     )(g, r)
     return sign[:nb], scale[:nb], newr[:nb]
 
@@ -134,5 +135,6 @@ def onebit_quant_packed(g, r, rows_per_step: int = 8,
             jax.ShapeDtypeStruct((nbp, block), jnp.float32),
         ],
         interpret=interpret,
+        name="onebit_quant_packed",
     )(g, r)
     return packed[:nb], scale[:nb], newr[:nb]

@@ -156,4 +156,5 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, dh), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(bt, ctx, win, q, k_pages, v_pages)

@@ -93,6 +93,7 @@ def topk_sparsify(x, k: int, rows_per_step: int = 8,
             jax.ShapeDtypeStruct((nbp, block), x.dtype),
         ],
         interpret=interpret,
+        name="topk_sparsify",
     )(x)
     return vals[:nb], idx[:nb], dense[:nb]
 
@@ -141,5 +142,6 @@ def topk_encode_ef(g, r, k: int, rows_per_step: int = 8,
             jax.ShapeDtypeStruct((nbp, block), jnp.float32),
         ],
         interpret=interpret,
+        name="topk_sparsify_ef",
     )(g, r)
     return vals[:nb], idx[:nb], newr[:nb]

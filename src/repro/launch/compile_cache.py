@@ -1,4 +1,5 @@
-"""Where JAX keeps its persistent compilation cache."""
+"""Where JAX keeps its persistent compilation cache, and how many programs
+the process has compiled."""
 
 from __future__ import annotations
 
@@ -24,3 +25,20 @@ def use_compile_cache() -> str:
         return env
     jax.config.update("jax_compilation_cache_dir", str(DEFAULT_DIR))
     return str(DEFAULT_DIR)
+
+
+_COMPILES = []
+
+
+def compile_count() -> int:
+    """Executables built so far in this process: each compiled, or read
+    from the persistent cache; a call whose executable is already in
+    memory builds none.  The first call registers the listener
+    (``jax.monitoring``) and returns 0, so call it once before the builds
+    that should count and take differences."""
+    if not _COMPILES:
+        _COMPILES.append(0)
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda name, secs, **kw: _COMPILES.append(secs)
+            if "backend_compile" in name else None)
+    return len(_COMPILES) - 1

@@ -32,7 +32,7 @@ from repro.core.compression import get_compressor
 from repro.core.precision import POLICIES, apply_policy, get_policy
 from repro.core.strategies import REGISTRY, get_strategy
 from repro.data.pipeline import DataConfig, bayes_entropy, prefetch_batches
-from repro.launch.compile_cache import use_compile_cache
+from repro.launch.compile_cache import compile_count, use_compile_cache
 from repro.models import transformer as T
 from repro.optim import adam, sgd, warmup_cosine
 from repro.train.loop import (init_train_state, make_loss_fn,
@@ -91,7 +91,9 @@ def build_argparser():
                          "exit 2 with a one-line message when the dir has "
                          "no valid step")
     ap.add_argument("--log-every", type=int, default=10)
-    ap.add_argument("--out", default=None, help="JSON metrics file")
+    ap.add_argument("--out", default=None,
+                    help="JSON metrics file: one record per logged step, "
+                         "with the executables built so far (compiles)")
     return ap
 
 
@@ -163,6 +165,7 @@ def resume_auto(ckpt_dir, state, strategy, comm, policy, strategy_name):
 
 def main(argv=None):
     use_compile_cache()
+    compile_count()  # count from here: the --out records carry the total
     args = build_argparser().parse_args(argv)
     try:
         cfg = get_config(args.arch)
@@ -254,7 +257,8 @@ def main(argv=None):
                    "wire_bytes": float(m["wire_bytes"]),
                    "wire_bytes_per_sample":
                        float(m["wire_bytes"]) / samples_per_step,
-                   "elapsed_s": round(time.time() - t0, 2)}
+                   "elapsed_s": round(time.time() - t0, 2),
+                   "compiles": compile_count()}
             if "loss_scale" in m:
                 rec["loss_scale"] = float(m["loss_scale"])
             history.append(rec)

@@ -38,6 +38,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core.scopes import OPTIMIZER, scoped
+
 
 # ---------------------------------------------------------------------------
 # learning-rate schedules
@@ -68,6 +70,14 @@ class Optimizer:
     init: Callable  # params -> opt_state
     update: Callable  # (grads, opt_state, params, t) -> (new_params, opt_state)
     state_floats: int = 0  # f32 state values kept per parameter element
+
+    def __post_init__(self):
+        # every update, by any strategy or step body, traces under the
+        # optimizer's phase scope (core/scopes.py)
+        if not getattr(self.update, "_optimizer_scoped", False):
+            update = scoped(OPTIMIZER)(self.update)
+            update._optimizer_scoped = True
+            object.__setattr__(self, "update", update)
 
 
 def state_template(opt: Optimizer, params):

@@ -28,6 +28,7 @@ from repro.core.comm import Comm, HierComm, LocalComm, ShardComm
 from repro.core.fabric import (BucketLayout, DEFAULT_BUCKET_BYTES, Fabric,
                                PartitionedLayout)
 from repro.core.precision import PrecisionPolicy
+from repro.core.scopes import FORWARD, OPTIMIZER, scoped
 from repro.core.strategies import Strategy
 from repro.models import transformer as T
 from repro.optim.optimizers import Optimizer, state_template
@@ -169,7 +170,7 @@ def make_replica_train_step(loss_fn, optimizer: Optimizer, strategy: Strategy,
         return acc, loss_sum, wire, ev
 
     if policy is None or policy.is_noop:
-        grad_fn = jax.vmap(jax.value_and_grad(loss_fn))
+        grad_fn = jax.vmap(jax.value_and_grad(scoped(FORWARD)(loss_fn)))
 
         def step(state, batches):
             src = state["params"]
@@ -210,9 +211,10 @@ def make_replica_train_step(loss_fn, optimizer: Optimizer, strategy: Strategy,
                 metrics["comm_events"] = metrics["comm_events"] \
                     + boundary_wire[1]
             metrics["loss"] = mean_loss
-            metrics["replica_divergence"] = _stack_divergence(
-                strategy.gather_params(params, comm) if owns_params
-                else params)
+            with jax.named_scope(OPTIMIZER):  # reads the updated replicas
+                metrics["replica_divergence"] = _stack_divergence(
+                    strategy.gather_params(params, comm) if owns_params
+                    else params)
             return new_state, metrics
 
         return _jit(step)
@@ -223,6 +225,7 @@ def make_replica_train_step(loss_fn, optimizer: Optimizer, strategy: Strategy,
         src = state.get("master", state["params"])
         fwd = strategy.gather_params(src, comm) if owns_params else src
 
+        @scoped(FORWARD)
         def scaled_loss(p_src, batch):
             # cast-params: forward consumes the param-dtype image of the
             # (possibly wider) source-of-truth copy
@@ -232,7 +235,8 @@ def make_replica_train_step(loss_fn, optimizer: Optimizer, strategy: Strategy,
         boundary_wire = None
         if accum_steps == 1:
             loss, grads = vgrad(fwd, batches)
-            grads = PR.unscale_grads(grads, scale)
+            with jax.named_scope(OPTIMIZER):
+                grads = PR.unscale_grads(grads, scale)
             mean_loss = jnp.mean(loss)
         elif part_accum:
             acc, loss_sum, wire, ev = accum_grads_part(fwd, batches, vgrad)
@@ -249,42 +253,45 @@ def make_replica_train_step(loss_fn, optimizer: Optimizer, strategy: Strategy,
             grads = lay.debucketize([a / (accum_steps * scale) for a in acc],
                                     cast=False)
             mean_loss = loss_sum / accum_steps
-        finite = PR.tree_finite(grads) if sstate is not None \
-            else jnp.asarray(True)
-        if boundary_wire is not None:
-            new_src, opt_state, comm_state, metrics = \
-                strategy.update_partitioned(
+        with jax.named_scope(OPTIMIZER):  # update, loss scale, skip-or-apply
+            finite = PR.tree_finite(grads) if sstate is not None \
+                else jnp.asarray(True)
+            if boundary_wire is not None:
+                new_src, opt_state, comm_state, metrics = \
+                    strategy.update_partitioned(
+                        src, grads, state["opt_state"], state["comm_state"],
+                        state["step"], optimizer, comm)
+            else:
+                new_src, opt_state, comm_state, metrics = strategy.update(
                     src, grads, state["opt_state"], state["comm_state"],
                     state["step"], optimizer, comm)
-            metrics = dict(metrics)
+            if sstate is not None:  # skip-or-apply
+                new_src = PR.select_tree(finite, new_src, src)
+                opt_state = PR.select_tree(finite, opt_state,
+                                           state["opt_state"])
+                comm_state = PR.select_tree(finite, comm_state,
+                                            state["comm_state"])
+            new_state = {"opt_state": opt_state, "comm_state": comm_state,
+                         "step": state["step"] + 1}
+            if "master" in state:
+                new_state["master"] = new_src
+                new_state["params"] = policy.cast_to_param(new_src)
+            else:
+                new_state["params"] = new_src
+            if sstate is not None:
+                new_state["loss_scale"] = PR.next_scale_state(policy, sstate,
+                                                              finite)
+        metrics = dict(metrics)
+        if boundary_wire is not None:
             metrics["wire_bytes"] = metrics["wire_bytes"] + boundary_wire[0]
             metrics["comm_events"] = metrics["comm_events"] \
                 + boundary_wire[1]
-        else:
-            new_src, opt_state, comm_state, metrics = strategy.update(
-                src, grads, state["opt_state"], state["comm_state"],
-                state["step"], optimizer, comm)
-        if sstate is not None:  # skip-or-apply
-            new_src = PR.select_tree(finite, new_src, src)
-            opt_state = PR.select_tree(finite, opt_state,
-                                       state["opt_state"])
-            comm_state = PR.select_tree(finite, comm_state,
-                                        state["comm_state"])
-        new_state = {"opt_state": opt_state, "comm_state": comm_state,
-                     "step": state["step"] + 1}
-        if "master" in state:
-            new_state["master"] = new_src
-            new_state["params"] = policy.cast_to_param(new_src)
-        else:
-            new_state["params"] = new_src
-        metrics = dict(metrics)
         metrics["loss"] = mean_loss / scale
-        metrics["replica_divergence"] = _stack_divergence(
-            strategy.gather_params(new_state["params"], comm) if owns_params
-            else new_state["params"])
+        with jax.named_scope(OPTIMIZER):  # reads the updated replicas
+            metrics["replica_divergence"] = _stack_divergence(
+                strategy.gather_params(new_state["params"], comm)
+                if owns_params else new_state["params"])
         if sstate is not None:
-            new_state["loss_scale"] = PR.next_scale_state(policy, sstate,
-                                                          finite)
             metrics["loss_scale"] = sstate["scale"]
             metrics["overflow"] = 1.0 - finite.astype(jnp.float32)
         return new_state, metrics
@@ -478,6 +485,7 @@ def make_sharded_train_step(cfg, optimizer: Optimizer,
     def value_and_grad(params, batch, scale):
         """cast-params → forward → scaled loss (the backward runs against
         the scaled objective; callers unscale in f32)."""
+        @scoped(FORWARD)
         def lfn(p):
             p = policy.cast_to_param(p) if policy is not None else p
             loss = loss_fn(p, batch)
@@ -581,7 +589,8 @@ def make_sharded_train_step(cfg, optimizer: Optimizer,
             if accum_steps == 1:
                 loss, grads = value_and_grad(params, batch, scale)
                 if scaling:
-                    grads = PR.unscale_grads(grads, scale)
+                    with jax.named_scope(OPTIMIZER):
+                        grads = PR.unscale_grads(grads, scale)
                 grads, new_r, _ = fab.exchange(grads, residual,
                                                pod_compressor)
             else:
@@ -631,7 +640,8 @@ def make_sharded_train_step(cfg, optimizer: Optimizer,
             if accum_steps == 1:
                 loss, grads = value_and_grad(params, batch, scale)
                 if scaling:
-                    grads = PR.unscale_grads(grads, scale)
+                    with jax.named_scope(OPTIMIZER):
+                        grads = PR.unscale_grads(grads, scale)
                 g_shards, _ = fab.exchange_partitioned(grads, play)
             elif zero_stage >= 2:
                 def micro(carry, mb):
@@ -652,9 +662,10 @@ def make_sharded_train_step(cfg, optimizer: Optimizer,
                 g_shards, _ = fab.exchange_partitioned_accumulated(acc, play)
             # every pod must take the same skip decision: the finite check
             # runs on this pod's reduced shards, pmin'ed across pods
-            ok = PR.tree_finite(g_shards).astype(jnp.float32) if scaling \
-                else jnp.ones((), jnp.float32)
-            ok = jax.lax.pmin(ok, "pod") if scaling else ok
+            with jax.named_scope(OPTIMIZER):
+                ok = PR.tree_finite(g_shards).astype(jnp.float32) \
+                    if scaling else jnp.ones((), jnp.float32)
+                ok = jax.lax.pmin(ok, "pod") if scaling else ok
             if keeps_master:
                 inner, p_shards = opt_state["opt"], opt_state["master"]
             else:
@@ -702,7 +713,8 @@ def make_sharded_train_step(cfg, optimizer: Optimizer,
             if accum_steps == 1:
                 loss, grads = value_and_grad(params, batch, scale)
                 if scaling:
-                    grads = PR.unscale_grads(grads, scale)
+                    with jax.named_scope(OPTIMIZER):
+                        grads = PR.unscale_grads(grads, scale)
                 g_shards, _ = fab.exchange_partitioned(grads, play)
             else:
                 def micro(carry, mb):
@@ -717,9 +729,10 @@ def make_sharded_train_step(cfg, optimizer: Optimizer,
                 denom = accum_steps * (scale if scaling else 1.0)
                 g_shards = [a / denom for a in acc]
                 loss = loss_sum / accum_steps
-            ok = PR.tree_finite(g_shards).astype(jnp.float32) if scaling \
-                else jnp.ones((), jnp.float32)
-            ok = jax.lax.pmin(ok, "pod") if scaling else ok
+            with jax.named_scope(OPTIMIZER):
+                ok = PR.tree_finite(g_shards).astype(jnp.float32) \
+                    if scaling else jnp.ones((), jnp.float32)
+                ok = jax.lax.pmin(ok, "pod") if scaling else ok
             new_shards, new_opt = optimizer.update(g_shards, opt_state,
                                                    p_shards, t)
             return (jax.lax.pmean(loss, "pod"), new_shards, new_opt, ok)
@@ -742,18 +755,19 @@ def make_sharded_train_step(cfg, optimizer: Optimizer,
             loss, params, opt_state, ok = body(
                 state["params"], batch, state["opt_state"], state["step"],
                 scale)
-            finite = ok > 0.5
-            if scaling:  # skip-or-apply
-                params = PR.select_tree(finite, params, state["params"])
-                opt_state = PR.select_tree(finite, opt_state,
-                                           state["opt_state"])
-                loss = loss / scale
-            new_state = {"params": params, "opt_state": opt_state,
-                         "comm_state": state["comm_state"],
-                         "step": state["step"] + 1}
-            if scaling:
-                new_state["loss_scale"] = PR.next_scale_state(
-                    policy, sstate, finite)
+            with jax.named_scope(OPTIMIZER):  # the loss-scale work
+                finite = ok > 0.5
+                if scaling:  # skip-or-apply
+                    params = PR.select_tree(finite, params, state["params"])
+                    opt_state = PR.select_tree(finite, opt_state,
+                                               state["opt_state"])
+                    loss = loss / scale
+                new_state = {"params": params, "opt_state": opt_state,
+                             "comm_state": state["comm_state"],
+                             "step": state["step"] + 1}
+                if scaling:
+                    new_state["loss_scale"] = PR.next_scale_state(
+                        policy, sstate, finite)
             return new_state, loss
         # dense paths: the f32 master (when the policy keeps one) lives in
         # state["master"] and is the source of truth — forward casts it to
@@ -774,33 +788,35 @@ def make_sharded_train_step(cfg, optimizer: Optimizer,
         else:
             loss, grads = sync_grads(src, batch, scale)
             if scaling:
-                grads = PR.unscale_grads(grads, scale)
+                with jax.named_scope(OPTIMIZER):
+                    grads = PR.unscale_grads(grads, scale)
             comm_state = state["comm_state"]
-        finite = PR.tree_finite(grads) if scaling else jnp.asarray(True)
-        if strategy is not None:
-            new_src, opt_state, comm_state, _ = strategy.update(
-                src, grads, state["opt_state"],
-                comm_state, state["step"], optimizer, comm)
-        else:
-            new_src, opt_state = optimizer.update(
-                grads, state["opt_state"], src, state["step"])
-        if scaling:  # skip-or-apply
-            new_src = PR.select_tree(finite, new_src, src)
-            opt_state = PR.select_tree(finite, opt_state,
-                                       state["opt_state"])
-            comm_state = PR.select_tree(finite, comm_state,
-                                        state["comm_state"])
-            loss = loss / scale
-        new_state = {"opt_state": opt_state, "comm_state": comm_state,
-                     "step": state["step"] + 1}
-        if "master" in state:
-            new_state["master"] = new_src
-            new_state["params"] = policy.cast_to_param(new_src)
-        else:
-            new_state["params"] = new_src
-        if scaling:
-            new_state["loss_scale"] = PR.next_scale_state(policy, sstate,
-                                                          finite)
+        with jax.named_scope(OPTIMIZER):  # update, loss scale, skip-or-apply
+            finite = PR.tree_finite(grads) if scaling else jnp.asarray(True)
+            if strategy is not None:
+                new_src, opt_state, comm_state, _ = strategy.update(
+                    src, grads, state["opt_state"],
+                    comm_state, state["step"], optimizer, comm)
+            else:
+                new_src, opt_state = optimizer.update(
+                    grads, state["opt_state"], src, state["step"])
+            if scaling:  # skip-or-apply
+                new_src = PR.select_tree(finite, new_src, src)
+                opt_state = PR.select_tree(finite, opt_state,
+                                           state["opt_state"])
+                comm_state = PR.select_tree(finite, comm_state,
+                                            state["comm_state"])
+                loss = loss / scale
+            new_state = {"opt_state": opt_state, "comm_state": comm_state,
+                         "step": state["step"] + 1}
+            if "master" in state:
+                new_state["master"] = new_src
+                new_state["params"] = policy.cast_to_param(new_src)
+            else:
+                new_state["params"] = new_src
+            if scaling:
+                new_state["loss_scale"] = PR.next_scale_state(policy, sstate,
+                                                              finite)
         return new_state, loss
 
     return step

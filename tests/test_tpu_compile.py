@@ -109,5 +109,8 @@ def test_paged_attention_compiles(one_chip):
     kv = _sds((n_pages, page, KV, DH), jnp.bfloat16, one_chip)
     tables = _sds((b, pages_per_seq), jnp.int32, one_chip)
     ctx = _sds((b,), jnp.int32, one_chip)
-    _compile(lambda *a: paged_attention(*a, interpret=False),
-             q, kv, kv, tables, ctx)
+    text = _compile(lambda *a: paged_attention(*a, interpret=False),
+                    q, kv, kv, tables, ctx)
+    # the kernel's instruction keeps the name paged_attention_roofline reads
+    assert any(line.split(" = ")[0].strip().startswith("%paged_attention")
+               for line in text.splitlines() if "tpu_custom_call" in line)
