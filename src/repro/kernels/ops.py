@@ -36,7 +36,7 @@ _interpret = default_interpret  # backward-compat alias
 
 
 def flash_attention(q, k, v, *, causal=True, window=-1,
-                    block_q=128, block_k=128):
+                    block_q=None, block_k=None):
     return _flash(q, k, v, causal=causal, window=window,
                   block_q=block_q, block_k=block_k)
 

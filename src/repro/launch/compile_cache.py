@@ -1,5 +1,5 @@
-"""Where JAX keeps its persistent compilation cache, and how many programs
-the process has compiled."""
+"""Where JAX keeps its persistent compilation cache, how many programs the
+process has compiled, and which attention path its traces took."""
 
 from __future__ import annotations
 
@@ -42,3 +42,29 @@ def compile_count() -> int:
             lambda name, secs, **kw: _COMPILES.append(secs)
             if "backend_compile" in name else None)
     return len(_COMPILES) - 1
+
+
+ATTENTION_PATH = "/repro/attention_path/"
+_PATHS = {}
+
+
+def record_attention_path(path: str, layers: int) -> None:
+    """Note, at trace time, that ``layers`` attention layers took ``path``
+    (``flash``: the Pallas kernel; ``dense``: the jnp path)."""
+    jax.monitoring.record_scalar(ATTENTION_PATH + path, layers)
+
+
+def attention_paths() -> dict:
+    """Attention layers traced so far in this process, by path: a trace of
+    a scanned stack counts each of its layers.  Like ``compile_count``,
+    the first call registers the listener and returns zeros."""
+    if not _PATHS:
+        _PATHS.update(flash=0, dense=0)
+
+        def count(name, value, **kw):
+            if name.startswith(ATTENTION_PATH):
+                path = name[len(ATTENTION_PATH):]
+                _PATHS[path] = _PATHS.get(path, 0) + int(value)
+
+        jax.monitoring.register_scalar_listener(count)
+    return dict(_PATHS)

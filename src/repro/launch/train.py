@@ -32,7 +32,8 @@ from repro.core.compression import get_compressor
 from repro.core.precision import POLICIES, apply_policy, get_policy
 from repro.core.strategies import REGISTRY, get_strategy
 from repro.data.pipeline import DataConfig, bayes_entropy, prefetch_batches
-from repro.launch.compile_cache import compile_count, use_compile_cache
+from repro.launch.compile_cache import (attention_paths, compile_count,
+                                        use_compile_cache)
 from repro.models import transformer as T
 from repro.optim import adam, sgd, warmup_cosine
 from repro.train.loop import (init_train_state, make_loss_fn,
@@ -93,7 +94,9 @@ def build_argparser():
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--out", default=None,
                     help="JSON metrics file: one record per logged step, "
-                         "with the executables built so far (compiles)")
+                         "with the executables built so far (compiles) "
+                         "and the attention layers traced so far by path "
+                         "(attention_paths: flash or dense)")
     return ap
 
 
@@ -166,6 +169,7 @@ def resume_auto(ckpt_dir, state, strategy, comm, policy, strategy_name):
 def main(argv=None):
     use_compile_cache()
     compile_count()  # count from here: the --out records carry the total
+    attention_paths()  # and which attention path each traced layer took
     args = build_argparser().parse_args(argv)
     try:
         cfg = get_config(args.arch)
@@ -258,7 +262,8 @@ def main(argv=None):
                    "wire_bytes_per_sample":
                        float(m["wire_bytes"]) / samples_per_step,
                    "elapsed_s": round(time.time() - t0, 2),
-                   "compiles": compile_count()}
+                   "compiles": compile_count(),
+                   "attention_paths": attention_paths()}
             if "loss_scale" in m:
                 rec["loss_scale"] = float(m["loss_scale"])
             history.append(rec)

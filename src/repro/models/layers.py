@@ -21,6 +21,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import FULL_ATTENTION, ModelConfig
 from repro.core import jax_compat as compat
+from repro.launch.compile_cache import record_attention_path
 from repro.launch.sharding import BATCH, MODEL, heads_ax, seq_ax, shard
 
 NEG_INF = -2.0e38
@@ -249,28 +250,70 @@ def _sdpa_decode(cfg: ModelConfig, q, k, v, mask):
     return out.reshape(b, lq, h, dh)
 
 
+def _sdpa_flash(q, k, v):
+    """Causal self-attention through the Pallas flash kernel (scores stay
+    in VMEM).  q: (B,L,H,Dh), k/v: (B,L,KV,Dh) → (B,L,H,Dh)."""
+    from repro.kernels import ops
+
+    out = ops.flash_attention(q.swapaxes(1, 2), k.swapaxes(1, 2),
+                              v.swapaxes(1, 2))
+    return out.swapaxes(1, 2)
+
+
+def _takes_flash(cfg: ModelConfig, plain_causal: bool) -> bool:
+    """Whether causal self-attention takes the flash kernel: only where its
+    semantics are exactly the kernel's (positions 0..L-1 and no window,
+    which the caller certifies with ``plain_causal``; no logit softcap),
+    where this device holds whole heads (no cp, no TP, no auto-partitioned
+    mesh axis larger than 1) and where the kernel compiles (a chip, not
+    interpret mode).  Everything else keeps the jnp ``_sdpa``."""
+    from repro.kernels import ops
+
+    if (not plain_causal or cfg.attn_logit_softcap
+            or cfg.sharding_mode == "cp" or cfg.tp_degree > 1
+            or _current_tp() is not None):
+        return False
+    mesh = compat.get_abstract_mesh()
+    if mesh is not None and not mesh.empty:
+        manual = compat.manual_axis_names(mesh)
+        if any(n > 1 for a, n in dict(mesh.shape).items()
+               if a not in manual):
+            return False
+    return not ops.default_interpret()
+
+
 def attention(p, cfg: ModelConfig, x, positions, window, theta,
               cache=None, cache_pos=None, memory=None, causal=True,
-              collect_cache=False):
+              collect_cache=False, plain_causal=False, stacked=1):
     """One attention sub-layer.
 
     Training: ``cache is None`` — full-sequence causal (+sliding window) attn;
               with ``collect_cache`` the full-sequence (k, v) are returned as
-              a populated decode cache (prefill).
+              a populated decode cache (prefill).  ``plain_causal`` (static)
+              says that ``positions`` are 0..L-1 and that no layer of the
+              stack has a window: see ``_takes_flash``.
     Decode:   ``cache`` holds (k, v) of length S; x has Lq=1; ``cache_pos`` is
               the write position.  Returns (out, new_cache).
     Cross-attention: ``memory`` is the encoder output; no cache, no causality.
+
+    Full-sequence calls record the path they took (``flash`` or ``dense``)
+    for the ``stacked`` layers this trace stands for
+    (``compile_cache.attention_paths``).
     """
     xkv = memory if memory is not None else x
     b, lq = x.shape[0], x.shape[1]
 
     if memory is not None:  # cross attention: full visibility
+        record_attention_path("dense", stacked)
         q, k, v = _qkv(p, cfg, x, xkv)
         lk = memory.shape[1]
         mask = jnp.ones((1, 1, lq, lk), bool)
         out = _sdpa(cfg, q, k, v, mask)
         new_cache = cache
     elif cache is None:  # training / prefill self-attention
+        flash = causal and _takes_flash(cfg, plain_causal)
+        record_attention_path("flash" if flash else "dense", stacked)
+
         def head_block(p_, xx):
             """One head-block's full attention subgraph: qkv slice → rope
             → sdpa over its heads → out-projection PARTIAL."""
@@ -279,7 +322,9 @@ def attention(p, cfg: ModelConfig, x, positions, window, theta,
             k = rope(k, positions, theta)
             q = shard(q, BATCH, seq_ax(cfg), heads_ax(cfg), None)
             k = shard(k, BATCH, seq_ax(cfg), heads_ax(cfg), None)
-            if (isinstance(window, int) and window > 0 and causal
+            if flash:
+                out = _sdpa_flash(q, k, v)
+            elif (isinstance(window, int) and window > 0 and causal
                     and lq % window == 0 and lq // window >= 2):
                 # static sliding window ⇒ block-banded attention: each q
                 # block attends only to (prev, self) k blocks — compute

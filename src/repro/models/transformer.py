@@ -50,8 +50,9 @@ def _init_layer(key, cfg: ModelConfig, spec: LayerSpec, cross: bool):
 
 def _apply_layer(p, cfg, spec, h, positions, window, theta, cache, cache_pos,
                  memory, causal=True, collect_cache=False, block_tables=None,
-                 paged_kernel=False):
-    """One (mixer → [cross] → ffn) layer. Returns (h, new_cache, aux)."""
+                 paged_kernel=False, plain_causal=False, stacked=1):
+    """One (mixer → [cross] → ffn) layer. Returns (h, new_cache, aux).
+    ``plain_causal`` and ``stacked`` are static: see ``L.attention``."""
     aux = jnp.zeros((), jnp.float32)
     x = L.rms_norm(h, p["pre_norm"], cfg.norm_eps)
     if spec.mixer == "attn":
@@ -63,7 +64,8 @@ def _apply_layer(p, cfg, spec, h, positions, window, theta, cache, cache_pos,
             out, new_cache = L.attention(
                 p["attn"], cfg, x, positions, window, theta, cache=cache,
                 cache_pos=cache_pos, causal=causal,
-                collect_cache=collect_cache)
+                collect_cache=collect_cache, plain_causal=plain_causal,
+                stacked=stacked)
     elif spec.mixer == "mamba":
         out, new_cache = S.mamba(p["mamba"], cfg, x, cache=cache,
                                  collect_cache=collect_cache)
@@ -80,7 +82,7 @@ def _apply_layer(p, cfg, spec, h, positions, window, theta, cache, cache_pos,
     if "cross_attn" in p and memory is not None:
         x = L.rms_norm(h, p["cross_norm"], cfg.norm_eps)
         out, _ = L.attention(p["cross_attn"], cfg, x, positions, window,
-                             theta, memory=memory)
+                             theta, memory=memory, stacked=stacked)
         h = h + out
 
     if spec.ffn != "none":
@@ -144,11 +146,18 @@ def init_model(key, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 def _run_stack(params, cfg: ModelConfig, h, positions, cache, cache_pos,
                memory, remat=False, collect_cache=False, block_tables=None,
-               paged_kernel=False):
+               paged_kernel=False, arange_positions=False):
+    """``arange_positions`` (static): ``positions`` are 0..L-1 in every
+    row, as ``forward`` and ``prefill`` make them."""
     specs, repeat = cfg.superblock()
     np_windows, np_thetas = cfg.layer_windows()  # (repeat, S) numpy arrays
     windows = jnp.asarray(np_windows)
     thetas = jnp.asarray(np_thetas)
+    # causal attention with nothing a flash kernel would not know: decided
+    # here, before the scan turns the windows into traced values
+    plain_causal = arange_positions and bool(
+        (np_windows == FULL_ATTENTION).all())
+    stacked = repeat if cfg.scan_layers else 1
 
     def superblock_body(carry, xs):
         h, aux_acc = carry
@@ -159,7 +168,8 @@ def _run_stack(params, cfg: ModelConfig, h, positions, cache, cache_pos,
             h, nc, aux = _apply_layer(
                 p_sb[str(i)], cfg, spec, h, positions, win_sb[i], th_sb[i],
                 c_i, cache_pos, memory, collect_cache=collect_cache,
-                block_tables=block_tables, paged_kernel=paged_kernel)
+                block_tables=block_tables, paged_kernel=paged_kernel,
+                plain_causal=plain_causal, stacked=stacked)
             new_cache_sb[str(i)] = nc if nc is not None else {}
         return (h, aux_acc + aux), new_cache_sb
 
@@ -253,12 +263,13 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None, positions=None,
     params = _cast_compute(params, cfg)
     h = _embed(params, cfg, tokens, embeds)
     b, l = h.shape[:2]
-    if positions is None:
+    arange_positions = positions is None
+    if arange_positions:
         positions = jnp.broadcast_to(jnp.arange(l, dtype=jnp.int32), (b, l))
     if cfg.is_encoder_decoder and memory is None:
         raise ValueError("encoder-decoder model requires encoder `memory`")
     h, aux, _ = _run_stack(params, cfg, h, positions, None, None, memory,
-                           remat=remat)
+                           remat=remat, arange_positions=arange_positions)
     return _logits(params, cfg, h), aux
 
 
@@ -273,7 +284,7 @@ def prefill(params, cfg: ModelConfig, tokens=None, embeds=None, memory=None,
     b, l = h.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(l, dtype=jnp.int32), (b, l))
     h, _, cache = _run_stack(params, cfg, h, positions, None, None, memory,
-                             collect_cache=True)
+                             collect_cache=True, arange_positions=True)
     if last_only:
         h = h[:, -1:]
     return _logits(params, cfg, h), cache
@@ -293,7 +304,8 @@ def encode(params, cfg: ModelConfig, embeds=None, tokens=None):
         h, _, _ = _apply_layer(p_layer, cfg, spec, h, positions,
                                jnp.int32(FULL_ATTENTION),
                                jnp.float32(cfg.rope_theta),
-                               None, None, None, causal=False)
+                               None, None, None, causal=False,
+                               stacked=cfg.num_encoder_layers)
         return (h, 0.0), None
 
     (h, _), _ = jax.lax.scan(body, (h, 0.0), enc["stack"])

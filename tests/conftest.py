@@ -48,3 +48,13 @@ def pytest_configure(config):
 @pytest.fixture(scope="session")
 def rng():
     return jax.random.PRNGKey(0)
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Entry points that pick interpret mode from the backend see the CPU
+    here; make them take their compiled branch, as on the chip.  Only
+    tracing and compiles for a described chip can follow: a kernel that
+    runs on the CPU needs interpret mode."""
+    import repro.kernels.ops as ops
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)

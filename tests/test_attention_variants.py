@@ -67,3 +67,108 @@ def test_gemma_window_pattern():
     assert globals_ == [5, 11, 17, 23]
     assert all(windows[i, 0] == 512 for i in range(26) if i not in globals_)
     assert thetas[5, 0] == 1_000_000.0 and thetas[0, 0] == 10_000.0
+
+
+# ---------------------------------------------------------------------------
+# which path full-sequence attention takes (flash kernel vs jnp _sdpa)
+# ---------------------------------------------------------------------------
+def _tiny(arch="qwen2-1.5b", **kw):
+    import dataclasses
+
+    from repro.configs import get_config
+
+    return dataclasses.replace(get_config(arch).reduced(), **kw)
+
+
+def _loss_grad(cfg, positions=None):
+    from repro.models import transformer as T
+    from repro.train.losses import lm_loss
+
+    params = jax.eval_shape(lambda: T.init_model(jax.random.PRNGKey(0), cfg))
+    toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+
+    def loss(p, t):
+        logits, aux = T.forward(p, cfg, tokens=t, positions=positions,
+                                remat=True)
+        return lm_loss(logits, t, aux)
+
+    return jax.value_and_grad(loss), (params, toks)
+
+
+def _cross_attention():
+    from repro.models import layers as L
+
+    cfg = _tiny()
+    p = L.init_attention(jax.random.PRNGKey(0), cfg)
+    x = jnp.zeros((2, 128, cfg.d_model))
+    pos = jnp.broadcast_to(jnp.arange(128), (2, 128))
+
+    def f(p, x):
+        return L.attention(p, cfg, x, pos, -1, cfg.rope_theta, memory=x,
+                           plain_causal=True)[0]
+
+    return f, (p, x)
+
+
+@pytest.mark.parametrize("case,paths", [
+    ("qwen2", {"flash": 2, "dense": 0}),
+    ("sliding_window", {"flash": 0, "dense": 2}),
+    ("cp", {"flash": 0, "dense": 2}),
+    ("given_positions", {"flash": 0, "dense": 2}),
+    ("cross_attention", {"flash": 0, "dense": 1}),
+    ("interpret_mode", {"flash": 0, "dense": 2}),
+])
+def test_attention_path_dispatch(case, paths, request):
+    """Tracing a loss (or one cross-attention call) records one path per
+    attention layer, and only the kernel's exact case takes the kernel:
+    arange positions, no window, whole heads, compiled kernels.  Tracing
+    only: nothing is lowered."""
+    import re
+
+    from repro.launch.compile_cache import attention_paths
+
+    if case != "interpret_mode":
+        request.getfixturevalue("compiled_kernels")
+    if case == "cross_attention":
+        fn, args = _cross_attention()
+    elif case == "sliding_window":
+        fn, args = _loss_grad(_tiny("gemma3-1b"))
+    elif case == "cp":
+        fn, args = _loss_grad(_tiny(sharding_mode="cp"))
+    elif case == "given_positions":
+        fn, args = _loss_grad(_tiny(), jnp.broadcast_to(jnp.arange(128),
+                                                        (2, 128)))
+    else:
+        fn, args = _loss_grad(_tiny())
+    before = attention_paths()
+    text = str(jax.make_jaxpr(fn)(*args))
+    after = attention_paths()
+    assert {k: after[k] - before[k] for k in paths} == paths
+    kernels = set(re.findall(r"flash_attention_(?:fwd|dq|dkv)", text))
+    want = {"flash_attention_fwd", "flash_attention_dq",
+            "flash_attention_dkv"} if paths["flash"] else set()
+    assert kernels == want
+
+
+def test_flash_path_matches_dense_model(monkeypatch):
+    """A tiny qwen2's loss and gradients through the flash path (kernels in
+    interpret mode) equal the dense path's in f32."""
+    from repro.launch.compile_cache import attention_paths
+    from repro.models import layers as L
+    from repro.models import transformer as T
+
+    cfg = _tiny()
+    fn, (_, toks) = _loss_grad(cfg)
+    params = T.init_model(jax.random.PRNGKey(1), cfg)
+    toks = jax.random.randint(jax.random.PRNGKey(2), toks.shape, 0,
+                              cfg.vocab_size)
+    loss_d, grads_d = jax.jit(fn)(params, toks)
+    monkeypatch.setattr(L, "_takes_flash", lambda cfg, plain: plain)
+    before = attention_paths()["flash"]
+    fn, _ = _loss_grad(cfg)  # a new function: traced anew
+    loss_f, grads_f = jax.jit(fn)(params, toks)
+    assert attention_paths()["flash"] - before == cfg.num_layers
+    assert abs(float(loss_f) - float(loss_d)) <= 1e-6 * abs(float(loss_d))
+    for gf, gd in zip(jax.tree.leaves(grads_f), jax.tree.leaves(grads_d)):
+        assert np.linalg.norm(np.asarray(gf - gd)) <= \
+            1e-5 * np.linalg.norm(np.asarray(gd))

@@ -67,6 +67,59 @@ def test_flash_attention_matches_model_attention(rng):
                                atol=3e-5, rtol=3e-5)
 
 
+def _rel_err(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+@pytest.mark.parametrize("groups", [1, 2, 6])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("l", [256, 300])  # two blocks of 128; padding
+@pytest.mark.parametrize("d", [64, 128])
+def test_flash_attention_grad_matches_sdpa(groups, dtype, l, d, rng):
+    """Causal GQA through the kernel, forward and ``jax.grad`` w.r.t. q, k
+    and v, against the model's dense ``_sdpa`` computed in f32, by the
+    relative norm of the difference.  In f32 the kernel agrees to 2e-5; in
+    bf16 it is no further from the f32 answer than ``_sdpa`` run in bf16
+    itself (the path it replaces)."""
+    from repro.configs.base import ModelConfig
+    from repro.models.layers import _sdpa
+
+    kv = 1 if groups == 6 else 2
+    h = groups * kv
+    cfg = ModelConfig(num_heads=h, num_kv_heads=kv)
+    ks = jax.random.split(rng, 4)
+    q = jax.random.normal(ks[0], (1, l, h, d), dtype)
+    k = jax.random.normal(ks[1], (1, l, kv, d), dtype)
+    v = jax.random.normal(ks[2], (1, l, kv, d), dtype)
+    do = jax.random.normal(ks[3], (1, l, h, d), dtype)
+    mask = (jnp.arange(l)[None, :] <= jnp.arange(l)[:, None])[None, None]
+
+    def flash(q, k, v):
+        return ops.flash_attention(q.swapaxes(1, 2), k.swapaxes(1, 2),
+                                   v.swapaxes(1, 2), block_q=128,
+                                   block_k=128).swapaxes(1, 2)
+
+    def dense(q, k, v):
+        return _sdpa(cfg, q, k, v, mask)
+
+    def out_and_grads(f, *args):
+        o, vjp = jax.vjp(f, *args[:3])
+        return (o,) + vjp(args[3])
+
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, do)]
+    want = out_and_grads(dense, *f32)
+    got = out_and_grads(flash, q, k, v, do)
+    if dtype == jnp.float32:
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            assert _rel_err(a, b) < 2e-5, name
+    else:
+        base = out_and_grads(dense, q, k, v, do)
+        for name, a, c, b in zip(("o", "dq", "dk", "dv"), got, base, want):
+            assert a.dtype == dtype, name
+            assert _rel_err(a, b) <= _rel_err(c, b), name
+
+
 # ---------------------------------------------------------------------------
 # top-k sparsify
 # ---------------------------------------------------------------------------

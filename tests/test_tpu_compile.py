@@ -47,14 +47,6 @@ def one_chip():
     cc.reset_cache()
 
 
-@pytest.fixture
-def compiled_kernels(monkeypatch):
-    """Entry points that pick interpret mode from the backend see the CPU
-    here; make them take their compiled branch, as on the chip."""
-    import repro.kernels.ops as ops
-    monkeypatch.setattr(ops, "default_interpret", lambda: False)
-
-
 def _compile(fn, *sds):
     text = jax.jit(fn).lower(*sds).compile().as_text()
     assert "tpu_custom_call" in text  # the Pallas kernel is in the program
@@ -114,3 +106,56 @@ def test_paged_attention_compiles(one_chip):
     # the kernel's instruction keeps the name paged_attention_roofline reads
     assert any(line.split(" = ")[0].strip().startswith("%paged_attention")
                for line in text.splitlines() if "tpu_custom_call" in line)
+
+
+L_CELL, B_CELL, H = 2048, 4, KV * G  # the training cell's sequences
+
+
+def test_flash_attention_compiles(one_chip):
+    """The flash kernel's forward and backward at the training cell's
+    shapes: 12 bf16 query heads grouped 6 to each of 2 KV heads of 128."""
+    from repro.kernels.flash_attention import flash_attention
+
+    q = _sds((B_CELL, H, L_CELL, DH), jnp.bfloat16, one_chip)
+    kv = _sds((B_CELL, KV, L_CELL, DH), jnp.bfloat16, one_chip)
+
+    def fwd_bwd(q, k, v, do):
+        o, vjp = jax.vjp(lambda *a: flash_attention(*a, interpret=False),
+                         q, k, v)
+        return o, vjp(do)
+
+    text = _compile(fwd_bwd, q, kv, kv, q)
+    kernels = {line.split(" = ")[0].strip().lstrip("%").split(".")[0]
+               for line in text.splitlines() if "tpu_custom_call" in line}
+    assert {"flash_attention_fwd", "flash_attention_dq",
+            "flash_attention_dkv"} <= kernels, kernels
+
+
+def test_layer_grad_holds_no_score_square(one_chip, compiled_kernels):
+    """One qwen2-1.5b layer's remat gradient in bf16 at the cell's shapes:
+    with the kernel on, no (B, H, L, L) buffer is left in the program."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.base import FULL_ATTENTION
+    from repro.models import transformer as T
+
+    cfg = dataclasses.replace(get_config("qwen2-1.5b").with_depth(1),
+                              param_dtype="bfloat16",
+                              compute_dtype="bfloat16")
+    spec = cfg.superblock()[0][0]
+    params = jax.eval_shape(
+        lambda: T._init_layer(jax.random.PRNGKey(0), cfg, spec, False))
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), params)
+    h = _sds((B_CELL, L_CELL, D_MODEL), jnp.bfloat16, one_chip)
+
+    @jax.checkpoint
+    def layer(p, h):
+        pos = jnp.broadcast_to(jnp.arange(L_CELL), (B_CELL, L_CELL))
+        out, _, _ = T._apply_layer(p, cfg, spec, h, pos, FULL_ATTENTION,
+                                   cfg.rope_theta, None, None, None,
+                                   plain_causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(layer, argnums=(0, 1)), params, h)
+    assert f"{H},{L_CELL},{L_CELL}]" not in text
