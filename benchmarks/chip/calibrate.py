@@ -43,12 +43,13 @@ def context(args, seed, root=spec.CHECKOUT, require_tpu=True):
     bench = spec.load_benchmark(root)
     cell = spec.cell(bench, args.workload)
     conf = spec.config(bench, cell["config"], root)
+    fam = spec.family(conf, root)
     devs = harness.check_device(cell["chips"] if args.seeds else 1,
                                 require_tpu)
     return harness.Ctx(args.workload, seed, args.seconds, False, cell, conf,
                        spec.traffic(cell["traffic"], root),
-                       harness.limits(cell["name"], root), spec.dims(conf),
-                       cell["chips"], peaks.peaks(devs[0].device_kind),
+                       harness.limits(cell["name"], root), fam.dims(conf),
+                       fam, cell["chips"], peaks.peaks(devs[0].device_kind),
                        Path(root), time.time(), devs)
 
 
@@ -64,7 +65,8 @@ def train(args, seeds, controls, **kw):
         prog = td.check_steps(tr)
         del tr
         gc.collect()
-        ref = reference.train_steps(seed, ctx.dims, td.reference_batches(ctx),
+        ref = reference.train_steps(ctx.family, seed, ctx.dims,
+                                    td.reference_batches(ctx),
                                     ctx.mix["optimizer"], devices=ctx.devices)
         refs[seed] = ref
         emit(kind="program", seed=seed, **td.compare(prog, ref),
@@ -75,7 +77,7 @@ def train(args, seeds, controls, **kw):
         batches = td.reference_batches(ctx)
         opt = ctx.mix["optimizer"]
         ref = refs.get(seed) or reference.train_steps(
-            seed, ctx.dims, batches, opt, devices=ctx.devices)
+            ctx.family, seed, ctx.dims, batches, opt, devices=ctx.devices)
         variants = {"control_fp8": dict(rnd=reference.fp8),
                     "fault_half_batch": dict(fault="half",
                                              n_chips=ctx.chips)}
@@ -83,8 +85,8 @@ def train(args, seeds, controls, **kw):
             variants["fault_no_exchange"] = dict(fault="no_exchange",
                                                  n_chips=ctx.chips)
         for name, v in variants.items():
-            got = reference.train_steps(seed, ctx.dims, batches, opt,
-                                        devices=ctx.devices, **v)
+            got = reference.train_steps(ctx.family, seed, ctx.dims, batches,
+                                        opt, devices=ctx.devices, **v)
             emit(kind=name, seed=seed, **td.compare(got, ref))
 
 
@@ -98,7 +100,7 @@ def serve(args, seeds, controls, **kw):
         rec, served, wkey, make = sd.serve(ctx)
         params = make(wkey)
         ctrl = seed in controls
-        gaps = reference.served_gaps(params, ctx.dims, served,
+        gaps = reference.served_gaps(ctx.family, params, ctx.dims, served,
                                      ctx.mix["max_seq"],
                                      rnd=reference.fp8 if ctrl else None)
         out = {"token_gap": float(max(g.max() for g in gaps["gaps"])),
@@ -115,7 +117,8 @@ def serve(args, seeds, controls, **kw):
             altered = list(gen)
             altered[len(gen) // 2] = (altered[len(gen) // 2] + 1) \
                 % ctx.dims["vocab"]
-            g = reference.served_gaps(params, ctx.dims, [(prompt, altered)],
+            g = reference.served_gaps(ctx.family, params, ctx.dims,
+                                      [(prompt, altered)],
                                       ctx.mix["max_seq"])["gaps"][0]
             emit(kind="fault_token_altered", seed=seed,
                  token_gap=float(np.max(g)))

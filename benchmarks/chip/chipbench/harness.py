@@ -35,6 +35,7 @@ class Ctx:
     mix: dict
     limits: dict
     dims: dict
+    family: object  # the module families/<family>.py
     chips: int
     peaks: dict
     root: Path
@@ -50,22 +51,6 @@ class Ctx:
 
     def log(self, msg: str) -> None:
         print(f"[{self.workload}] {msg}", file=sys.stderr, flush=True)
-
-
-_COMPILES = []
-
-
-def compile_count() -> int:
-    """Backend compilations so far in this process (a listener is
-    registered on the first call)."""
-    import jax
-
-    if not _COMPILES:
-        _COMPILES.append(0)
-        jax.monitoring.register_event_duration_secs_listener(
-            lambda name, secs, **kw: _COMPILES.append(secs)
-            if "backend_compile" in name else None)
-    return len(_COMPILES) - 1
 
 
 def parse(argv):
@@ -115,6 +100,7 @@ def run(argv, *, t0: float = None, root: Path = spec.CHECKOUT,
     bench = spec.load_benchmark(root)
     cell = spec.cell(bench, args.workload)
     conf = spec.config(bench, cell["config"], root)
+    fam = spec.family(conf, root)
     mix = spec.traffic(cell["traffic"], root)
     lim = limits(cell["name"], root)
 
@@ -127,7 +113,7 @@ def run(argv, *, t0: float = None, root: Path = spec.CHECKOUT,
         return None
     kind = devs[0].device_kind
     ctx = Ctx(args.workload, args.seed, args.seconds, bool(args.trace), cell,
-              conf, mix, lim, spec.dims(conf), cell["chips"],
+              conf, mix, lim, fam.dims(conf), fam, cell["chips"],
               peaks_mod.peaks(kind), Path(root), t0, devs,
               Path(root) / ".bench_trace" / args.workload)
     ctx.log(f"device {devs[0].platform} {kind} x{len(devs)}; compile "

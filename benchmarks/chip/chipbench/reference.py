@@ -1,14 +1,16 @@
-"""The plain reference: a Qwen2 decoder in ``jax.numpy`` and float32, from
-the published description (arXiv:2407.10671, arXiv:2412.15115), with no
+"""The plain reference: a decoder in ``jax.numpy`` and float32, with no
 kernel, cache, batching or sharding, and nothing imported from the
 program.
 
-Per layer: x += Wo·attn(RoPE(Wq·n(x) + bq), RoPE(Wk·n(x) + bk),
-Wv·n(x) + bv) with grouped KV heads (query head i reads KV head i // G);
-then x += Wd·(silu(Wg·n(x)) ⊙ Wu·n(x)).  n is RMSNorm with a learned
-scale (no offset), RoPE rotates the two halves of each head with
-θ^(-2i/Dh).  The head is the tied embedding or ``lm_head``.  Every matrix
-product runs at ``Precision.HIGHEST``.
+What is the same for every family is here: the matrix product ``mm``
+(every product runs at ``Precision.HIGHEST``), ``rms_norm``, ``rope``,
+the chunked loss, the row gradient, Adam, the token stream, and the
+serving and training drivers.  What belongs to one architecture is its
+family's (``families/<family>.py``), which every driver takes as its
+first argument: ``embed(params, tokens, dims)``, ``plan(dims)`` (each
+layer as its function, the path of the stack it reads and its index
+there), the layer functions ``(p, x, pos, dims, rnd)`` and
+``head(params, h, dims, rnd)``.
 
 ``rnd`` rounds the operands of every matrix product: ``None`` is float32;
 ``fp8`` rounds both operands to float8 e4m3 with a scale per tensor (and
@@ -42,7 +44,7 @@ def _einsum(spec, a, b):
                       preferred_element_type=jnp.float32)
 
 
-def _mm(spec, a, b, rnd):
+def mm(spec, a, b, rnd):
     """A matrix product in float32, or with ``rnd`` a product whose
     operands are rounded, in the forward pass and, with the incoming
     gradient rounded on its own scale, in the backward pass."""
@@ -52,7 +54,7 @@ def _mm(spec, a, b, rnd):
         return _einsum(spec, a, b)
 
     @jax.custom_vjp
-    def mm(x, y):
+    def rounded(x, y):
         return _einsum(spec, rnd(x), rnd(y))
 
     def fwd(x, y):
@@ -63,8 +65,8 @@ def _mm(spec, a, b, rnd):
         _, vjp = jax.vjp(lambda x, y: _einsum(spec, x, y), *res)
         return vjp(rnd(g))
 
-    mm.defvjp(fwd, bwd)
-    return mm(a, b)
+    rounded.defvjp(fwd, bwd)
+    return rounded(a, b)
 
 
 def rms_norm(x, scale, eps):
@@ -83,62 +85,31 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def layer(p, x, pos, dims, rnd=None):
-    """One decoder layer on one sequence; ``p`` holds this layer's slices
-    (any float dtype), x (S, D) float32."""
-    eps, theta = dims["eps"], dims["theta"]
-    f32 = jnp.float32
-    a = p["attn"]
-    h = rms_norm(x, p["pre_norm"]["scale"], eps)
-    q = _mm("sd,dhk->shk", h, a["wq"], rnd) + a["bq"].astype(f32)
-    k = _mm("sd,dhk->shk", h, a["wk"], rnd) + a["bk"].astype(f32)
-    v = _mm("sd,dhk->shk", h, a["wv"], rnd) + a["bv"].astype(f32)
-    q, k = rope(q, pos, theta), rope(k, pos, theta)
-    g = dims["heads"] // dims["kv_heads"]
-    k = jnp.repeat(k, g, axis=1)
-    v = jnp.repeat(v, g, axis=1)
-    s = _mm("qhk,shk->hqs", q, k, rnd) * dims["head_dim"] ** -0.5
-    causal = pos[None, :, None] >= pos[None, None, :]
-    s = jnp.where(causal, s, -jnp.inf)
-    o = _mm("hqs,shk->qhk", jax.nn.softmax(s, axis=-1), v, rnd)
-    x = x + _mm("qhk,hkd->qd", o, a["wo"], rnd)
-    h = rms_norm(x, p["ffn_norm"]["scale"], eps)
-    m = p["mlp"]
-    u = jax.nn.silu(_mm("sd,df->sf", h, m["w_gate"], rnd)) \
-        * _mm("sd,df->sf", h, m["w_up"], rnd)
-    return x + _mm("sf,fd->sd", u, m["w_down"], rnd)
+def _at(params, path):
+    for k in path:
+        params = params[k]
+    return params
 
 
-def layer_slice(params, i):
-    return jax.tree.map(lambda w: w[i], params["stack"]["0"])
-
-
-def head(params, h, dims, rnd=None):
-    h = rms_norm(h, params["final_norm"]["scale"], dims["eps"])
-    if dims["tied"]:
-        return _mm("sd,vd->sv", h, params["embed"], rnd)
-    return _mm("sd,dv->sv", h, params["lm_head"], rnd)
-
-
-def embed(params, tokens):
-    return params["embed"][tokens].astype(jnp.float32)
+def _layer_of(stack, i):
+    return jax.tree.map(lambda w: w[i], stack)
 
 
 # ---------------------------------------------------------------------------
 # serving: logits of whole sequences, one layer at a time
 # ---------------------------------------------------------------------------
-def sequence_logits(params, tokens, dims, rnd=None):
+def sequence_logits(fam, params, tokens, dims, rnd=None):
     """(S, V) float32 logits of one sequence; runs layer by layer, each
     layer's weights upcast inside its own call, so that it fits beside
     weights kept in bfloat16.  Pad ``tokens`` to one length to compile
     once: a position never sees a later one."""
     tokens = jnp.asarray(tokens, jnp.int32)
     pos = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    x = _embed_jit(params["embed"], tokens)
-    for i in range(dims["layers"]):
-        x = _layer_jit(params["stack"]["0"], jnp.int32(i), x, pos,
-                       _hashable(dims), rnd)
-    return _head_jit(params, x, _hashable(dims), rnd)
+    hd = _hashable(dims)
+    x = _embed_jit(fam, params, tokens, hd)
+    for fn, path, i in fam.plan(dims):
+        x = _layer_jit(_at(params, path), jnp.int32(i), x, pos, hd, rnd, fn)
+    return _head_jit(fam, params, x, hd, rnd)
 
 
 class _hashable(dict):
@@ -146,17 +117,19 @@ class _hashable(dict):
         return hash(tuple(sorted(self.items())))
 
 
-_embed_jit = jax.jit(lambda table, tokens: embed({"embed": table}, tokens))
+@partial(jax.jit, static_argnums=(0, 3))
+def _embed_jit(fam, params, tokens, dims):
+    return fam.embed(params, tokens, dims)
 
 
-@partial(jax.jit, static_argnums=(4, 5))
-def _layer_jit(stack, i, x, pos, dims, rnd):
-    return layer(jax.tree.map(lambda w: w[i], stack), x, pos, dims, rnd)
+@partial(jax.jit, static_argnums=(4, 5, 6))
+def _layer_jit(stack, i, x, pos, dims, rnd, fn):
+    return fn(_layer_of(stack, i), x, pos, dims, rnd)
 
 
-@partial(jax.jit, static_argnums=(2, 3))
-def _head_jit(params, x, dims, rnd):
-    return head(params, x, dims, rnd)
+@partial(jax.jit, static_argnums=(0, 3, 4))
+def _head_jit(fam, params, x, dims, rnd):
+    return fam.head(params, x, dims, rnd)
 
 
 def token_gaps(logits, positions, tokens) -> np.ndarray:
@@ -168,7 +141,7 @@ def token_gaps(logits, positions, tokens) -> np.ndarray:
     return np.asarray(best - own)
 
 
-def served_gaps(params, dims, served, pad_to: int, rnd=None) -> dict:
+def served_gaps(fam, params, dims, served, pad_to: int, rnd=None) -> dict:
     """``served``: list of (prompt, generated) token lists.  Returns the
     per-token gaps of the served tokens, and with ``rnd`` also the gaps of
     the tokens that the rounded model puts first, both against the
@@ -179,10 +152,10 @@ def served_gaps(params, dims, served, pad_to: int, rnd=None) -> dict:
         padded = np.zeros(pad_to, np.int32)
         padded[:len(seq)] = seq
         pos = np.arange(len(prompt) - 1, len(prompt) - 1 + len(gen))
-        ref = sequence_logits(params, padded, dims)
+        ref = sequence_logits(fam, params, padded, dims)
         out["gaps"].append(token_gaps(ref, pos, np.asarray(gen)))
         if rnd is not None:
-            low = sequence_logits(params, padded, dims, rnd)
+            low = sequence_logits(fam, params, padded, dims, rnd)
             first = jnp.argmax(low[jnp.asarray(pos)], -1)
             out["control_gaps"].append(token_gaps(ref, pos, first))
         del ref
@@ -195,14 +168,14 @@ def served_gaps(params, dims, served, pad_to: int, rnd=None) -> dict:
 LOSS_CHUNK = 512
 
 
-def row_loss(params, tokens, dims, rnd=None):
+def row_loss(fam, params, tokens, dims, rnd=None):
     """Mean next-token cross-entropy of one row, the head applied to
     chunks of positions so that the logits never exist whole."""
     pos = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    x = embed(params, tokens)
-    for i in range(dims["layers"]):
-        x = jax.checkpoint(layer, static_argnums=(3, 4))(
-            layer_slice(params, i), x, pos, _hashable(dims), rnd)
+    x = fam.embed(params, tokens, dims)
+    for fn, path, i in fam.plan(dims):
+        x = jax.checkpoint(fn, static_argnums=(3, 4))(
+            _layer_of(_at(params, path), i), x, pos, _hashable(dims), rnd)
     h, labels = x[:-1], tokens[1:]
     n = h.shape[0]
     c = min(LOSS_CHUNK, n)
@@ -213,7 +186,7 @@ def row_loss(params, tokens, dims, rnd=None):
 
     @jax.checkpoint
     def chunk(hc, lc, vc):
-        lg = head(params, hc, dims, rnd)
+        lg = fam.head(params, hc, dims, rnd)
         nll = jax.nn.logsumexp(lg, -1) - jnp.take_along_axis(
             lg, lc[:, None], -1)[:, 0]
         return jnp.sum(jnp.where(vc, nll, 0.0))
@@ -222,9 +195,10 @@ def row_loss(params, tokens, dims, rnd=None):
     return total / n
 
 
-@partial(jax.jit, static_argnums=(3, 4))
-def _row_grad(params, acc, tokens, dims, rnd):
-    loss, g = jax.value_and_grad(row_loss)(params, tokens, dims, rnd)
+@partial(jax.jit, static_argnums=(0, 4, 5))
+def _row_grad(fam, params, acc, tokens, dims, rnd):
+    loss, g = jax.value_and_grad(row_loss, argnums=1)(fam, params, tokens,
+                                                      dims, rnd)
     return loss, jax.tree.map(jnp.add, acc, g)
 
 
@@ -257,7 +231,7 @@ def _mix_shards(parts, n):
     return jax.tree.map(one, *parts)
 
 
-def _mean_grad(params, rows, devices, rnd, hd):
+def _mean_grad(fam, params, rows, devices, rnd, hd):
     """Mean loss and gradient over ``rows``, the rows spread over
     ``devices`` (each holds a copy of the parameters); the gradient ends
     on the first device."""
@@ -266,7 +240,7 @@ def _mean_grad(params, rows, devices, rnd, hd):
     losses = []
     for i, row in enumerate(rows):
         k = i % len(devices)
-        loss, accs[k] = _row_grad(reps[k], accs[k],
+        loss, accs[k] = _row_grad(fam, reps[k], accs[k],
                                   jax.device_put(jnp.asarray(row),
                                                  devices[k]), hd, rnd)
         losses.append(loss)
@@ -278,7 +252,7 @@ def _mean_grad(params, rows, devices, rnd, hd):
         jax.tree.map(lambda a: a / n, total)
 
 
-def train_steps(seed, dims, batches, opt, rnd=None, fault=None,
+def train_steps(fam, seed, dims, batches, opt, rnd=None, fault=None,
                 n_chips=1, devices=None) -> dict:
     """Three (or ``len(batches)``) steps of Adam on the float32 model from
     the seed's weights.  ``batches``: list of (B, L) int32 arrays, the
@@ -294,7 +268,7 @@ def train_steps(seed, dims, batches, opt, rnd=None, fault=None,
     over its own chip's rows only."""
     hd = _hashable(dims)
     devices = list(devices or jax.devices()[:1])
-    init = jax.jit(lambda k: weights.init(k, dims, jnp.float32))
+    init = jax.jit(lambda k: weights.init(fam, k, dims, jnp.float32))
     wkey = jax.device_put(weights.key(seed), devices[0])
     params = init(wkey)
     m = jax.tree.map(jnp.zeros_like, params)
@@ -305,13 +279,14 @@ def train_steps(seed, dims, batches, opt, rnd=None, fault=None,
         if fault == "half":
             chips = [c[: max(1, len(c) // 2)] for c in chips]
         if fault == "no_exchange":
-            parts = [_mean_grad(params, c, devices, rnd, hd) for c in chips]
+            parts = [_mean_grad(fam, params, c, devices, rnd, hd)
+                     for c in chips]
             loss = sum(p[0] for p in parts) / len(parts)
             g = _mix_shards([p[1] for p in parts], n_chips)
             del parts
         else:
-            loss, g = _mean_grad(params, np.concatenate(chips), devices,
-                                 rnd, hd)
+            loss, g = _mean_grad(fam, params, np.concatenate(chips),
+                                 devices, rnd, hd)
         losses.append(loss)
         if first is None:
             first = {k: float(x) for k, x in _flat(_leaf_norms(g)).items()}

@@ -26,7 +26,6 @@ import time
 import numpy as np
 
 from chipbench import flops, reference, traffic, weights
-from chipbench.program import model_config, tree_paths
 
 SAMPLE_TOKENS = 300
 SAMPLE_MAX = 8
@@ -80,7 +79,7 @@ def run(ctx) -> dict:
     rec, served, wkey, make = serve(ctx)
     t_ref = time.perf_counter()
     params = make(wkey)
-    gaps = reference.served_gaps(params, ctx.dims, served,
+    gaps = reference.served_gaps(ctx.family, params, ctx.dims, served,
                                  ctx.mix["max_seq"])
     del params
     token_gap = float(max(g.max() for g in gaps["gaps"]))
@@ -101,22 +100,21 @@ def serve(ctx):
 
     from repro.core.precision import apply_policy, get_policy
     from repro.models import transformer as T
+    from repro.launch.compile_cache import compile_count
     from repro.serve.engine import PagedDecodeEngine, Request
 
-    from chipbench.harness import compile_count
-
-    mix, dims, seed = ctx.mix, ctx.dims, ctx.seed
-    cfg = apply_policy(model_config(ctx.conf, dims),
+    mix, dims, seed, fam = ctx.mix, ctx.dims, ctx.seed, ctx.family
+    cfg = apply_policy(fam.program_config(ctx.conf, dims),
                        get_policy(ctx.conf["precision"]))
     wdt = jnp.dtype(cfg.param_dtype)
-    want = tree_paths(weights.shapes(dims))
-    got = tree_paths(jax.eval_shape(
+    want = weights.tree_paths(fam.shapes(dims))
+    got = weights.tree_paths(jax.eval_shape(
         lambda: T.init_model(jax.random.PRNGKey(0), cfg)))
     if want != got:
         raise ValueError(f"the program's parameter tree differs from the "
                          f"benchmark's: {want} vs {got}")
     wkey = weights.key(seed)
-    make = jax.jit(lambda k: weights.init(k, dims, wdt))
+    make = jax.jit(lambda k: weights.init(fam, k, dims, wdt))
     params = make(wkey)
     eng = PagedDecodeEngine(params, cfg, batch_slots=mix["batch_slots"],
                             max_seq=mix["max_seq"],
@@ -237,8 +235,8 @@ def serve(ctx):
         "compiles_in_window": in_window, "peaks": ctx.peaks, "chips": ctx.chips,
         "dims": dims, "traced_step_s": step_t,
         "traced_model_flops": sum(
-            flops.prefill_flops(dims, pos, rows)
-            + (flops.decode_flops(dims, c) if c else 0.0)
+            fam.prefill_flops(dims, pos, rows)
+            + (fam.decode_flops(dims, c) if c else 0.0)
             for pos, rows, c in flops_rec),
         "traced_paged_attention": [
             flops.paged_attention_cost(dims, c, kv_bytes, wdt.itemsize)

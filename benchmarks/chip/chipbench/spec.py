@@ -1,8 +1,26 @@
 """Find a cell's parts by name: ``BENCHMARK.json`` at the checkout's root,
-``configs/<config>.json`` (the file the benchmark names), ``traffic/<mix>.json``
+``configs/<config>.json`` (the file the benchmark names), the family module
+``families/<family>.py`` that the configuration names, ``traffic/<mix>.json``
 and ``metrics/<metric>.py`` beside this package.  A later change adds a
-configuration, a mix or a metric as a new file and a new entry, and edits
-nothing that is there."""
+configuration, a family, a mix or a metric as a new file and a new entry,
+and edits nothing that is there.
+
+A family module holds what depends on the architecture, and exports:
+
+* ``dims(conf)``: the sizes the rest uses, from the file's published keys;
+  a dict of numbers, strings and tuples that holds at least ``vocab``,
+  ``layers``, ``heads``, ``kv_heads`` and ``head_dim``;
+* ``program_config(conf, dims)``: the program's ``ModelConfig`` cut to the
+  file's depth, every published size it depends on checked against the
+  file (a difference raises);
+* ``shapes(dims)`` and ``std(path, shape)``: the parameter tree as the
+  program keeps it, and each leaf's (mean, std) (``weights.init``);
+* the reference's parts (``reference``): ``embed``, ``plan``, the layer
+  functions it names, ``head``;
+* FLOP counts (``flops``): ``train_flops_per_token(dims, seq_len)``,
+  ``prefill_flops(dims, positions, logit_rows)``,
+  ``decode_flops(dims, ctx_lens)``, and the cost of any kernel that its
+  cells' readers read."""
 
 from __future__ import annotations
 
@@ -72,26 +90,39 @@ def metric_reader(name: str, root: Path = CHECKOUT):
     if not path.is_file():
         raise SpecError(f"no reader {path} for metric {name!r}")
     mod_spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        "chipbench_metric_" + _ident(name), path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
     return mod.read
 
 
-def dims(conf: dict) -> dict:
-    """The sizes ``flops`` and the reference use, from a configuration
-    file's published keys."""
-    c = conf["config"]
-    return {
-        "d": c["hidden_size"],
-        "heads": c["num_attention_heads"],
-        "kv_heads": c["num_key_value_heads"],
-        "head_dim": c.get("head_dim",
-                          c["hidden_size"] // c["num_attention_heads"]),
-        "ff": c["intermediate_size"],
-        "vocab": c["vocab_size"],
-        "layers": c["num_hidden_layers"],
-        "eps": c["rms_norm_eps"],
-        "theta": c["rope_theta"],
-        "tied": c["tie_word_embeddings"],
-    }
+_FAMILIES = {}
+
+
+def family(conf: dict, root: Path = CHECKOUT):
+    """The family module that a configuration file names under
+    ``"family"``, from ``families/<family>.py`` (loaded once a file)."""
+    name = conf.get("family")
+    if not name:
+        raise SpecError(f"configuration {conf.get('name', '?')!r} names no "
+                        f"family (a \"family\" key)")
+    path = (Path(root) / CHIP_DIR.relative_to(CHECKOUT) / "families"
+            / f"{name}.py").resolve()
+    if not path.is_file():
+        raise SpecError(f"no family module {path} for family {name!r}")
+    if path not in _FAMILIES:
+        mod_spec = importlib.util.spec_from_file_location(
+            "chipbench_family_" + _ident(name), path)
+        mod = importlib.util.module_from_spec(mod_spec)
+        mod_spec.loader.exec_module(mod)
+        _FAMILIES[path] = mod
+    return _FAMILIES[path]
+
+
+def dims(conf: dict, root: Path = CHECKOUT) -> dict:
+    """The sizes of a configuration, as its family reads them."""
+    return family(conf, root).dims(conf)
+
+
+def _ident(name: str) -> str:
+    return name.replace(".", "_").replace("-", "_")

@@ -16,6 +16,13 @@ are compared, each by the worst leaf:
   leaving out leaves whose reference gradient is under a thousandth of
   the median leaf's (a key bias under softmax: Adam moves it by
   round-off alone).
+
+The program's trace-time counters (``/repro/...``) are taken over the
+build of the step.  A traced run (``--trace 1``) profiles ``trace_steps``
+steps from 40 % into the window; after the window it compiles the step it
+ran once more, outside the window and set-up, and hands the compiled text
+to the trace reduction, which joins device ops to the program's phases
+and scopes by instruction name.
 """
 
 from __future__ import annotations
@@ -28,11 +35,39 @@ from collections import deque
 
 import numpy as np
 
-from chipbench import flops, reference, traffic, weights
-from chipbench.program import model_config, tree_paths
+from chipbench import reference, traffic, weights
 
 CHECK_STEPS = 3
 ZERO_GRAD_FRACTION = 1e-3
+
+COUNTER_PREFIX = "/repro/"
+_COUNTERS = {}
+_LISTENING = []
+
+
+def _count(name, value, **kw):
+    if name.startswith(COUNTER_PREFIX):
+        _COUNTERS[name] = _COUNTERS.get(name, 0) + value
+
+
+def program_counters() -> dict:
+    """The program's trace-time counters so far in this process: every
+    ``jax.monitoring`` scalar whose name starts with ``/repro/``, summed by
+    name.  The first call registers the listener; take differences
+    (``counters_since``)."""
+    import jax
+
+    if not _LISTENING:
+        _LISTENING.append(True)
+        jax.monitoring.register_scalar_listener(_count)
+    return dict(_COUNTERS)
+
+
+def counters_since(before: dict) -> dict:
+    """The counters that moved since ``before``, by how much."""
+    now = program_counters()
+    return {k: v - before.get(k, 0) for k, v in sorted(now.items())
+            if v != before.get(k, 0)}
 
 
 def data_step0(seed: int) -> int:
@@ -89,13 +124,13 @@ class Trainer:
         from repro.optim.optimizers import adam
         from repro.train.loop import zero1_opt_template
 
-        job, dims, seed = ctx.mix, ctx.dims, ctx.seed
+        job, dims, seed, fam = ctx.mix, ctx.dims, ctx.seed, ctx.family
         self.chips, self.bpc, self.seq = ctx.chips, job["batch_per_chip"], \
             job["seq_len"]
         self.batch = self.bpc * self.chips
         zero = job["zero_stage"]
         opt = job["optimizer"]
-        cfg = model_config(ctx.conf, dims)
+        cfg = fam.program_config(ctx.conf, dims)
         self.mesh = Mesh(np.array(ctx.devices).reshape(self.chips, 1, 1),
                          ("pod", "data", "model"))
         step, (state_sds, batch_sds), (state_sh, self.batch_sh), donate = \
@@ -103,8 +138,8 @@ class Trainer:
                                             self.batch, "train"),
                              self.mesh, precision=ctx.conf["precision"],
                              zero_stage=zero)
-        want = tree_paths(weights.shapes(dims))
-        got = tree_paths(state_sds["params"])
+        want = weights.tree_paths(fam.shapes(dims))
+        got = weights.tree_paths(state_sds["params"])
         if want != got:
             raise ValueError(f"the program's parameter tree differs from "
                              f"the benchmark's: {want} vs {got}")
@@ -114,7 +149,7 @@ class Trainer:
         self.wkey = weights.key(seed)
 
         def make_state(wkey):
-            master = weights.init(wkey, dims, jnp.float32)
+            master = weights.init(fam, wkey, dims, jnp.float32)
             st = {"params": jax.tree.map(lambda x: x.astype(pdt), master),
                   "comm_state": {}, "step": jnp.zeros((), jnp.int32)}
             if zero:
@@ -169,7 +204,7 @@ class Trainer:
         self.grad_readout = jax.jit(
             lambda st: norms(jax.tree.map(lambda m: m / (1 - b1), m_of(st))))
         self.change_readout = jax.jit(lambda st, k: norms(jax.tree.map(
-            jnp.subtract, master_of(st), weights.init(k, dims,
+            jnp.subtract, master_of(st), weights.init(fam, k, dims,
                                                       jnp.float32))))
 
     def feed(self, t: int):
@@ -186,6 +221,14 @@ class Trainer:
         with set_mesh(self.mesh):
             self.state, loss = self.fn(self.state, batch)
         return loss
+
+    def step_text(self, batch) -> str:
+        """The compiled text of the step that ran, lowered again from its
+        state and a batch of the window's shape."""
+        from repro.core.jax_compat import set_mesh
+
+        with set_mesh(self.mesh):
+            return self.fn.lower(self.state, batch).compile().as_text()
 
 
 def check_steps(tr) -> dict:
@@ -211,13 +254,15 @@ def check_steps(tr) -> dict:
 def run(ctx) -> dict:
     import jax
 
+    from repro.launch.compile_cache import compile_count
+
+    before = program_counters()
     tr = Trainer(ctx)
     prog = check_steps(tr)
-    from chipbench.harness import compile_count
-
+    counters = counters_since(before)
     n_compiles = compile_count()
     per_step_tokens = tr.batch * tr.seq
-    per_step = flops.train_flops_per_token(ctx.dims, tr.seq) \
+    per_step = ctx.family.train_flops_per_token(ctx.dims, tr.seq) \
         * per_step_tokens
     batch_s, losses, pending = [], [], deque()
     traced = None
@@ -249,13 +294,17 @@ def run(ctx) -> dict:
     failed = sum(not math.isfinite(x) for x in losses)
     peak = ctx.memory_peak()
     ctx.log(f"window {window:.3f} s, {steps} steps, {in_window} compiles in "
-            f"the window, last loss {losses[-1] if losses else None}")
+            f"the window, last loss {losses[-1] if losses else None}; "
+            f"program counters {counters}")
+    step_text = tr.step_text(tr.feed(t)) if ctx.trace else None
+    bpc, seq = tr.bpc, tr.seq
     del tr
     gc.collect()
 
     t_ref = time.perf_counter()
-    ref = reference.train_steps(ctx.seed, ctx.dims, reference_batches(ctx),
-                                ctx.mix["optimizer"], devices=ctx.devices)
+    ref = reference.train_steps(ctx.family, ctx.seed, ctx.dims,
+                                reference_batches(ctx), ctx.mix["optimizer"],
+                                devices=ctx.devices)
     ctx.log(f"reference {time.perf_counter() - t_ref:.1f} s; losses "
             f"{prog['losses']} vs {ref['losses']}")
     got = compare(prog, ref)
@@ -269,7 +318,9 @@ def run(ctx) -> dict:
         "window_s": window, "steps": steps, "batch_s": batch_s,
         "flops_per_step": per_step, "chips": ctx.chips, "peaks": ctx.peaks,
         "traced_steps": traced, "compiles_in_window": in_window,
-        "trace": _reduce(ctx) if ctx.trace else None,
+        "counters": counters, "dims": ctx.dims, "batch_per_chip": bpc,
+        "seq_len": seq,
+        "trace": _reduce(ctx, step_text) if ctx.trace else None,
     }
 
 
@@ -299,11 +350,16 @@ def _traced_steps(ctx, tr, t, batch_s, losses):
     return n, t
 
 
-def _reduce(ctx) -> dict:
+def _reduce(ctx, step_text) -> dict:
     import shutil
 
     from chipbench.trace_reduce import load_xplane, reduce_trace
 
-    red = reduce_trace(load_xplane(str(ctx.trace_dir)))
+    red = reduce_trace(load_xplane(str(ctx.trace_dir)), step_text=step_text)
+    per_step = 1e3 / ctx.mix["trace_steps"]
+    ctx.log(f"traced: busy {red['busy_s'] * per_step:.3f} ms a step; by "
+            f"phase (ms a step) " + ", ".join(
+                f"{k} {v * per_step:.3f}" for k, v in
+                sorted(red["phase_s"].items(), key=lambda kv: -kv[1])))
     shutil.rmtree(ctx.trace_dir, ignore_errors=True)
     return red

@@ -2,15 +2,23 @@
 the traced part of the window, in %: the least time the chip could take
 for the decode calls' attention, max(bytes / peak bytes/s, FLOPs / peak
 FLOP/s) with ``flops.paged_attention_cost`` per layer, over the kernel's
-summed device time (ops whose name holds ``paged_attention``).  The
-bytes bound it: one query token reads a whole context."""
+summed device time (ops whose instruction is named ``paged_attention``,
+not the ops that read its output).  The bytes bound it: one query token
+reads a whole context."""
+
+import re
+
+from chipbench.trace_reduce import instruction
+
+KERNEL = re.compile(r"paged_attention(\.\d+)?")
 
 
 def read(rec, trace):
     calls = rec.get("traced_paged_attention") or []
     if not trace or not calls:
         return None
-    t = sum(v for k, v in trace["op_s"].items() if "paged_attention" in k)
+    t = sum(v for k, v in trace["op_s"].items()
+            if KERNEL.fullmatch(instruction(k)))
     if t <= 0:
         return None
     layers = rec["dims"]["layers"]

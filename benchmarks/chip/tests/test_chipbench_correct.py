@@ -43,27 +43,30 @@ def _fails(res):
 
 
 def test_training_control_in_float8_fails_the_limits():
-    dims = spec.dims({"config": tiny.TINY})
+    fam = spec.family({"family": "qwen2"})
+    dims = fam.dims({"config": tiny.TINY})
     rows = [reference.train_tokens(0, s, 1, 2, 32, 256, 0.9, 31, 7)
             for s in range(3)]
     opt = tiny.TRAIN["optimizer"]
     for seed in (1, 2, 3):
-        ref = reference.train_steps(seed, dims, rows, opt)
-        low = reference.train_steps(seed, dims, rows, opt,
+        ref = reference.train_steps(fam, seed, dims, rows, opt)
+        low = reference.train_steps(fam, seed, dims, rows, opt,
                                     rnd=reference.fp8)
         got = training.compare(low, ref)
         assert any(got[k] > v for k, v in tiny.LIMITS["train"].items()), got
 
 
 def test_serving_control_in_float8_fails_the_limit():
-    dims = spec.dims({"config": dict(tiny.TINY, tie_word_embeddings=False)})
-    params = reference.weights.init(reference.weights.key(4), dims,
+    fam = spec.family({"family": "qwen2"})
+    dims = fam.dims({"config": dict(tiny.TINY, tie_word_embeddings=False)})
+    params = reference.weights.init(fam, reference.weights.key(4), dims,
                                     jnp.bfloat16)
     import numpy as np
     r = np.random.default_rng(0)
     served = [(r.integers(0, 256, 30), list(r.integers(0, 256, 12)))
               for _ in range(3)]
-    g = reference.served_gaps(params, dims, served, 64, rnd=reference.fp8)
+    g = reference.served_gaps(fam, params, dims, served, 64,
+                              rnd=reference.fp8)
     worst = max(float(x.max()) for x in g["control_gaps"])
     assert worst > tiny.LIMITS["serve"]["token_gap"]
 

@@ -69,7 +69,7 @@ def test_discovery_finds_new_files_with_no_edit(tmp_path):
     chip = root / "benchmarks" / "chip"
     before = {p: p.read_bytes() for p in chip.rglob("*") if p.is_file()}
     (chip / "configs" / "tiny-wide.json").write_text(json.dumps(
-        {"config": dict(tiny.TINY, intermediate_size=256)}))
+        {"family": "qwen2", "config": dict(tiny.TINY, intermediate_size=256)}))
     (chip / "traffic" / "train.tiny.long.json").write_text(json.dumps(
         dict(tiny.TRAIN, seq_len=64)))
     (chip / "metrics" / "steps.train.py").write_text(
@@ -93,7 +93,7 @@ def test_discovery_finds_new_files_with_no_edit(tmp_path):
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     b = spec.load_benchmark(root)
     cell = spec.cell(b, "train.tiny-wide.long")
-    assert spec.dims(spec.config(b, cell["config"], root))["ff"] == 256
+    assert spec.dims(spec.config(b, cell["config"], root), root)["ff"] == 256
     assert spec.traffic(cell["traffic"], root)["seq_len"] == 64
     names = [m["name"] for m in spec.metrics(b, cell["name"], "per_layer")]
     assert names == ["steps.train"]
@@ -199,3 +199,56 @@ def test_traced_run_reports_per_layer_metrics(tmp_path, cpu_run,
         assert m["value"] > 0, name
     assert res["breakdown"]["device_ops"][0][0].startswith("fusion.2")
     assert res["correct"]
+
+
+PHASE_READERS = ["forward_ms.train", "backward_ms.train",
+                 "recompute_ms.train", "optimizer_ms.train",
+                 "flash_attention_roofline", "attention_flash_share.train"]
+
+
+def test_traced_train_run_reports_the_phase_readers(tmp_path, cpu_run,
+                                                    monkeypatch):
+    """The six readers added to a root as entries only.  The CPU has no
+    TPU plane, so the trace is made from the compiled text the run hands
+    to the reduction: each instruction of the step that runs as an op
+    takes 1 us, inside a run of the step's module.  The phase readers read
+    those; the CPU takes the dense attention path, so the attention share
+    reads 0 and no flash kernel runs."""
+    from chipbench import trace_reduce
+
+    from repro.core import scopes
+
+    reduce = trace_reduce.reduce_trace
+    seen = {}
+
+    def from_text(trace, top=10, step_text=None):
+        assert step_text and step_text.startswith("HloModule jit_step")
+        phases = scopes.phases(step_text)
+        ops = [[f"%{n} = f32[] op()", i * 1e3, 1e3]
+               for i, n in enumerate(sorted(phases))]
+        end = len(ops) * 1e3
+        seen["phases"] = list(phases.values())
+        return reduce({"devices": {"0": ops},
+                       "modules": {"0": [["jit_step(1)", 0.0, end]]},
+                       "host": [["bench.window", 0.0, end]]}, top,
+                      step_text=step_text)
+
+    monkeypatch.setattr(trace_reduce, "load_xplane", lambda path: {})
+    monkeypatch.setattr(trace_reduce, "reduce_trace", from_text)
+    root = tiny.make_root(tmp_path, precision="bf16")
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    for name in PHASE_READERS:
+        bench["per_layer"].append({
+            "name": name, "unit": "%" if "roofline" in name else "ms",
+            "better": "lower", "source": "device_trace", "layer": "t",
+            "moves": "train_tokens_per_s", "workloads": ["train.tiny"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    res = cpu_run(root, "train.tiny", seconds=2.0, trace=1)
+    assert res["correct"]
+    steps = tiny.TRAIN["trace_steps"]
+    for phase in ("forward", "backward", "recompute", "optimizer"):
+        want = 1e-3 * seen["phases"].count(phase) / steps
+        assert res["metrics"][f"{phase}_ms.train"]["value"] == \
+            pytest.approx(want)
+    assert res["metrics"]["attention_flash_share.train"]["value"] == 0.0
+    assert "flash_attention_roofline" not in res["metrics"]
