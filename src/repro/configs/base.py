@@ -10,10 +10,53 @@ super-block, so the scan stays homogeneous.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 from typing import Optional
 
 FULL_ATTENTION = -1  # sentinel: no sliding window
+
+
+@dataclass(frozen=True)
+class Yarn:
+    """YaRN rescaling of RoPE (transformers' ``_compute_yarn_parameters``):
+    frequencies whose wavelength fits ``beta_fast`` times or more into the
+    original context keep θ^(-2i/d), those fitting ``beta_slow`` times or
+    fewer are divided by ``factor``, with a linear ramp between; cos and
+    sin are both scaled by ``attention_factor`` (default 0.1·ln(factor)
+    + 1)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+    @property
+    def scale(self) -> float:
+        if self.attention_factor is not None:
+            return self.attention_factor
+        return 0.1 * math.log(self.factor) + 1.0
+
+    def inv_freq(self, theta: float, dh: int):
+        """(dh/2,) float64 inverse frequencies."""
+        import numpy as np
+
+        def dim(rotations):
+            return dh * math.log(self.original_max_position
+                                 / (rotations * 2 * math.pi)) \
+                / (2 * math.log(theta))
+
+        low = max(math.floor(dim(self.beta_fast)), 0)
+        high = min(math.ceil(dim(self.beta_slow)), dh - 1)
+        if low == high:
+            high += 0.001
+        half = dh // 2
+        extrap = theta ** -(np.arange(half, dtype=np.float64) * 2.0 / dh)
+        ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                       / (high - low), 0.0, 1.0)
+        keep = 1.0 - ramp  # 1: extrapolate (θ^(-2i/d)), 0: interpolate
+        return extrap / self.factor * (1.0 - keep) + extrap * keep
 
 
 @dataclass(frozen=True)
@@ -24,6 +67,7 @@ class LayerSpec:
     ffn: str = "mlp"  # mlp | moe | none
     window: int = FULL_ATTENTION  # sliding window (tokens); -1 = full attention
     rope_theta: float = 10_000.0
+    rope_yarn: Optional[Yarn] = None
 
 
 @dataclass(frozen=True)
@@ -53,6 +97,7 @@ class ModelConfig:
     sliding_window: Optional[int] = None  # window for "local" layers
     global_every: Optional[int] = None  # 1 global layer per N (gemma3: 6)
     global_rope_theta: Optional[float] = None  # rope theta for global layers
+    global_rope_yarn: Optional[Yarn] = None  # YaRN on the global layers
 
     # MoE ---------------------------------------------------------------------
     num_experts: int = 0
@@ -63,6 +108,16 @@ class ModelConfig:
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
     expert_pad_to: int = 16  # pad expert count to a multiple (EP divisibility)
+    # this device's share of the experts: ``held_experts`` of them from
+    # ``first_held_expert`` on (None: all).  The router stays
+    # ``num_experts`` wide; the layer computes its own experts' part.
+    held_experts: Optional[int] = None
+    first_held_expert: int = 0
+    # the router's product is a constant of the backward: neither the
+    # router nor the layer's input learns through the gates.  A share that
+    # runs alone cannot compute that gradient: it needs every expert's
+    # output, and the experts held elsewhere add nothing here.
+    frozen_router: bool = False
 
     # SSM / hybrid ------------------------------------------------------------
     attn_every: Optional[int] = None  # hybrid: 1 attn layer per N (jamba: 8)
@@ -123,6 +178,14 @@ class ModelConfig:
                     f"{self.name}: {f}={v!r} is not a supported precision "
                     f"dtype; choose one of {ALLOWED_DTYPES} "
                     "(see core/precision.py)")
+        if self.held_experts is not None and not (
+                0 < self.held_experts and 0 <= self.first_held_expert
+                and self.first_held_expert + self.held_experts
+                <= self.num_experts):
+            raise ValueError(
+                f"{self.name}: experts {self.first_held_expert}.."
+                f"{self.first_held_expert + self.held_experts - 1} are not "
+                f"a share of {self.num_experts}")
         t = self.tp_degree
         if t < 1:
             raise ValueError(f"{self.name}: tp_degree must be >= 1, got {t}")
@@ -151,6 +214,19 @@ class ModelConfig:
         p = self.expert_pad_to
         return -(-self.num_experts // p) * p if self.num_experts else 0
 
+    @property
+    def experts_held(self) -> int:
+        """Experts whose weights this device holds (dummy pads included
+        when it holds them all)."""
+        if self.held_experts is None:
+            return self.num_experts_padded
+        return self.held_experts
+
+    def with_expert_share(self, held: int, first: int = 0) -> "ModelConfig":
+        """The share of one device of ``num_experts // held`` that split
+        every expert layer: experts ``first .. first + held - 1``."""
+        return replace(self, held_experts=held, first_held_expert=first)
+
     # ---- layer-stack structure ------------------------------------------------
     def superblock(self) -> tuple:
         """(specs, repeat): the decoder stack is ``specs`` repeated ``repeat``×."""
@@ -173,30 +249,25 @@ class ModelConfig:
         specs = (LayerSpec(mixer="attn", ffn=ffn, rope_theta=self.rope_theta),)
         return specs, n
 
-    def layer_windows(self):
-        """Per-layer (window, rope_theta) for uniform attention stacks.
-
-        Returns arrays of shape (repeat, len(superblock)) used as scanned
-        values — this is how gemma3's 5:1 local:global pattern rides a
-        homogeneous scan.
-        """
-        import numpy as np
-
-        specs, repeat = self.superblock()
-        s = len(specs)
-        windows = np.full((repeat, s), FULL_ATTENTION, dtype=np.int32)
-        thetas = np.full((repeat, s), self.rope_theta, dtype=np.float32)
-        if self.sliding_window is not None:
-            n = self.num_layers
-            assert s == 1, "sliding-window patterns only supported on uniform stacks"
-            for li in range(n):
-                if self.global_every and (li + 1) % self.global_every == 0:
-                    windows[li, 0] = FULL_ATTENTION
-                    thetas[li, 0] = self.global_rope_theta or self.rope_theta
-                else:
-                    windows[li, 0] = self.sliding_window
-                    thetas[li, 0] = self.rope_theta
-        return windows, thetas
+    def window_pattern(self) -> Optional[tuple]:
+        """The attention of each position of one period of a uniform
+        attention stack with windows: ``global_every`` positions (or 1),
+        each a ``LayerSpec`` with its static window, RoPE θ and YaRN, the
+        last position global where ``global_every`` is set.  None where
+        the stack has no window (or is not a uniform attention stack)."""
+        specs, _ = self.superblock()
+        if self.sliding_window is None or len(specs) != 1 \
+                or specs[0].mixer != "attn":
+            return None
+        period = self.global_every or 1
+        local = replace(specs[0], window=self.sliding_window,
+                        rope_theta=self.rope_theta)
+        if not self.global_every:
+            return (local,)
+        glob = replace(specs[0], window=FULL_ATTENTION,
+                       rope_theta=self.global_rope_theta or self.rope_theta,
+                       rope_yarn=self.global_rope_yarn)
+        return (local,) * (period - 1) + (glob,)
 
     def with_sliding_window(self, window: int = 4096) -> "ModelConfig":
         """Sliding-window *variant* for long-context decode on full-attention
@@ -336,5 +407,5 @@ def _ensure_loaded():
     from repro.configs import (  # noqa: F401
         gemma3_1b, deepseek_67b, seamless_m4t_medium, xlstm_125m,
         qwen25_14b, qwen2_moe_a27b, granite_moe_1b, pixtral_12b,
-        jamba_15_large, qwen2_15b,
+        jamba_15_large, qwen2_15b, mellum2_12b_a25b,
     )

@@ -202,13 +202,21 @@ class ShardComm(Comm):
         ``reduce_scatter`` — leaving the ACCUMULATION dtype to the
         caller.  This is how the fabric realizes a narrow (bf16) wire
         with f32 accumulation: the wire op carries only the narrow
-        chunks (core/fabric.py::exchange_partitioned)."""
+        chunks (core/fabric.py::exchange_partitioned).  Where 128
+        divides C the wire moves (W, ..., C/128, 128): the same bytes,
+        and a (W, C) all-to-all of a large bucket compiles for minutes on
+        a TPU."""
         def one(x):
             c = x.shape[-1] // self.size
             y = x.reshape(x.shape[:-1] + (self.size, c))
             y = jnp.moveaxis(y, -2, 0)  # (W, ..., C): piece w -> worker w
-            return jax.lax.all_to_all(y, self.axis_name, split_axis=0,
-                                      concat_axis=0)
+            if c % 128:
+                return jax.lax.all_to_all(y, self.axis_name, split_axis=0,
+                                          concat_axis=0)
+            y = y.reshape(y.shape[:-1] + (c // 128, 128))
+            y = jax.lax.all_to_all(y, self.axis_name, split_axis=0,
+                                   concat_axis=0)
+            return y.reshape(y.shape[:-2] + (c,))
 
         return jax.tree.map(one, tree)
 

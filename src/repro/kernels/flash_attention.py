@@ -22,7 +22,11 @@ GQA rides the BlockSpec index maps: query head ``h`` reads KV head
 window masks are applied inside a block only where the block crosses the
 mask's edge; blocks the mask hides entirely are *skipped* via ``@pl.when``,
 and under a causal mask their index maps repeat the last live block, so the
-pipeline fetches nothing for them.
+pipeline fetches nothing for them.  Under a causal sliding window the
+inner grid axis walks only the band of blocks the window can reach (from
+the first block it reaches, as many steps as the widest band), and the
+kernels are named ``flash_attention_window_fwd`` / ``_dq`` / ``_dkv``, so
+that a trace tells them from the plain causal calls.
 
 Precision: bf16 inputs feed the MXU as bf16 with f32 accumulation; the
 softmax statistics, the log-sum-exp and dS stay f32, and the probabilities
@@ -104,13 +108,55 @@ def _visible(spec: _Spec, q_start, k_start, shape, keys_in_rows=False):
     return mask
 
 
-def _each_live_block(spec: _Spec, q_start, k_start, body):
-    """Run ``body(masked)`` on a live block: unmasked where the whole block
-    is visible, masked where it crosses the mask's edge."""
+def _each_live_block(spec: _Spec, q_start, k_start, body, in_range=None):
+    """Run ``body(masked)`` on a live block (and ``in_range``, where
+    given): unmasked where the whole block is visible, masked where it
+    crosses the mask's edge."""
     live = _live(spec, q_start, k_start)
+    if in_range is not None:
+        live = jnp.logical_and(live, in_range)
     whole = _whole(spec, q_start, k_start)
     pl.when(jnp.logical_and(live, whole))(lambda: body(False))
     pl.when(jnp.logical_and(live, jnp.logical_not(whole)))(lambda: body(True))
+
+
+def _banded(spec: _Spec) -> bool:
+    return spec.causal and spec.window > 0
+
+
+def _kv_block(spec: _Spec, qi, ki):
+    """kv block of step ``ki`` of q block ``qi``: in a band, counted from
+    the first block the window reaches."""
+    if not _banded(spec):
+        return ki
+    return jnp.maximum(qi * spec.block_q - spec.window + 1, 0) \
+        // spec.block_k + ki
+
+
+def _q_block(spec: _Spec, ki, qi):
+    """q block of step ``qi`` of kv block ``ki``: in a band, counted from
+    the block of the diagonal."""
+    if not _banded(spec):
+        return qi
+    return (ki * spec.block_k) // spec.block_q + qi
+
+
+def _bands(spec: _Spec, nq: int, nk: int) -> tuple:
+    """Steps of the inner grid axis: (kv steps a q block, q steps a kv
+    block); the widest band under a window, else every block."""
+    if not _banded(spec):
+        return nk, nq
+    bq, bk, w = spec.block_q, spec.block_k, spec.window
+    kv = max(min((q * bq + bq - 1) // bk, nk - 1)
+             - max(q * bq - w + 1, 0) // bk + 1 for q in range(nq))
+    qs = max(min((k * bk + bk - 1 + w - 1) // bq, nq - 1)
+             - (k * bk) // bq + 1 for k in range(nk))
+    return kv, qs
+
+
+def _name(spec: _Spec, kernel: str) -> str:
+    return f"flash_attention_window_{kernel}" if _banded(spec) \
+        else f"flash_attention_{kernel}"
 
 
 def _last_live_kv(spec: _Spec, qi, ki):
@@ -118,15 +164,21 @@ def _last_live_kv(spec: _Spec, qi, ki):
     mask the blocks past the diagonal repeat the last live one."""
     if not spec.causal:
         return ki
-    return jnp.minimum(ki, (qi * spec.block_q + spec.block_q - 1)
+    return jnp.minimum(_kv_block(spec, qi, ki),
+                       (qi * spec.block_q + spec.block_q - 1)
                        // spec.block_k)
 
 
-def _first_live_q(spec: _Spec, ki, qi):
+def _first_live_q(spec: _Spec, ki, qi, nq: int):
     """q block to fetch at step ``qi`` of kv block ``ki``: under a causal
-    mask the blocks before the diagonal repeat the first live one."""
+    mask the blocks before the diagonal repeat the first live one; in a
+    band the steps past the window (or the last block) repeat the last."""
     if not spec.causal:
         return qi
+    if _banded(spec):
+        last = (ki * spec.block_k + spec.block_k - 1 + spec.window - 1) \
+            // spec.block_q
+        return jnp.minimum(_q_block(spec, ki, qi), jnp.minimum(last, nq - 1))
     return jnp.maximum(qi, (ki * spec.block_k) // spec.block_q)
 
 
@@ -143,7 +195,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q_start, k_start = qi * spec.block_q, ki * spec.block_k
+    q_start = qi * spec.block_q
+    k_start = _kv_block(spec, qi, ki) * spec.block_k
 
     def body(masked: bool):
         s = lax.dot_general(q_ref[...], k_ref[...], NT,
@@ -179,7 +232,7 @@ def _fwd(q, k, v, spec: _Spec):
     kvh = k.shape[1]
     g = h // kvh
     bq, bk = spec.block_q, spec.block_k
-    grid = (b, h, lq // bq, k.shape[2] // bk)
+    grid = (b, h, lq // bq, _bands(spec, lq // bq, k.shape[2] // bk)[0])
 
     def q_map(bi, hi, qi, ki):
         return bi, hi, qi, 0
@@ -214,7 +267,7 @@ def _fwd(q, k, v, spec: _Spec):
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary")),
         interpret=spec.interpret,
-        name="flash_attention_fwd",
+        name=_name(spec, "fwd"),
     )(q, k, v)
 
 
@@ -229,7 +282,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_start, k_start = qi * spec.block_q, ki * spec.block_k
+    q_start = qi * spec.block_q
+    k_start = _kv_block(spec, qi, ki) * spec.block_k
 
     def body(masked: bool):
         k = k_ref[...]
@@ -255,7 +309,7 @@ def _dq(q, k, v, do, lse, di, spec: _Spec):
     b, h, lq, d = q.shape
     g = h // k.shape[1]
     bq, bk = spec.block_q, spec.block_k
-    grid = (b, h, lq // bq, k.shape[2] // bk)
+    grid = (b, h, lq // bq, _bands(spec, lq // bq, k.shape[2] // bk)[0])
 
     def q_map(bi, hi, qi, ki):
         return bi, hi, qi, 0
@@ -279,12 +333,12 @@ def _dq(q, k, v, do, lse, di, spec: _Spec):
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary")),
         interpret=spec.interpret,
-        name="flash_attention_dq",
+        name=_name(spec, "dq"),
     )(q, k, v, do, lse, di)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
-                dk_acc, dv_acc, *, spec: _Spec, scale: float):
+                dk_acc, dv_acc, *, spec: _Spec, scale: float, nq: int):
     ki, gi, qi = pl.program_id(2), pl.program_id(3), pl.program_id(4)
 
     @pl.when(jnp.logical_and(gi == 0, qi == 0))
@@ -292,7 +346,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_start, k_start = qi * spec.block_q, ki * spec.block_k
+    q_start = _q_block(spec, ki, qi) * spec.block_q
+    k_start = ki * spec.block_k
 
     def body(masked: bool):
         q, do = q_ref[...], do_ref[...]
@@ -311,7 +366,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
         dk_acc[...] += jnp.dot(ds.astype(q.dtype), q,
                                preferred_element_type=jnp.float32)
 
-    _each_live_block(spec, q_start, k_start, body)
+    # a band's steps past the last q block (the fetch repeats it) see none
+    _each_live_block(spec, q_start, k_start, body,
+                     in_range=_q_block(spec, ki, qi) < nq
+                     if _banded(spec) else None)
 
     @pl.when(jnp.logical_and(gi == pl.num_programs(3) - 1,
                              qi == pl.num_programs(4) - 1))
@@ -325,13 +383,14 @@ def _dkv(q, k, v, do, lse, di, spec: _Spec):
     kvh, lk = k.shape[1], k.shape[2]
     g = h // kvh
     bq, bk = spec.block_q, spec.block_k
-    grid = (b, kvh, lk // bk, g, lq // bq)
+    nq = lq // bq
+    grid = (b, kvh, lk // bk, g, _bands(spec, nq, lk // bk)[1])
 
     def q_map(bi, kv, ki, gi, qi):
-        return bi, kv * g + gi, _first_live_q(spec, ki, qi), 0
+        return bi, kv * g + gi, _first_live_q(spec, ki, qi, nq), 0
 
     def row_map(bi, kv, ki, gi, qi):
-        return bi, kv * g + gi, 0, _first_live_q(spec, ki, qi)
+        return bi, kv * g + gi, 0, _first_live_q(spec, ki, qi, nq)
 
     def kv_map(bi, kv, ki, gi, qi):
         return bi, kv, ki, 0
@@ -340,7 +399,7 @@ def _dkv(q, k, v, do, lse, di, spec: _Spec):
     kv_spec = pl.BlockSpec((None, None, bk, d), kv_map)
     row_spec = pl.BlockSpec((None, None, 1, bq), row_map)
     return pl.pallas_call(
-        functools.partial(_dkv_kernel, spec=spec, scale=d ** -0.5),
+        functools.partial(_dkv_kernel, spec=spec, scale=d ** -0.5, nq=nq),
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[kv_spec, kv_spec],
@@ -351,7 +410,7 @@ def _dkv(q, k, v, do, lse, di, spec: _Spec):
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary", "arbitrary")),
         interpret=spec.interpret,
-        name="flash_attention_dkv",
+        name=_name(spec, "dkv"),
     )(q, k, v, do, lse, di)
 
 

@@ -15,6 +15,7 @@ import jax
 
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.fused_adam import fused_adam as _adam
+from repro.kernels.grouped_matmul import grouped_matmul as _gmm
 from repro.kernels.mamba_scan import mamba_scan as _mamba
 from repro.kernels.onebit_quant import onebit_quant as _onebit
 from repro.kernels.onebit_quant import onebit_quant_packed as _onebit_packed
@@ -39,6 +40,10 @@ def flash_attention(q, k, v, *, causal=True, window=-1,
                     block_q=None, block_k=None):
     return _flash(q, k, v, causal=causal, window=window,
                   block_q=block_q, block_k=block_k)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    return _gmm(lhs, rhs, group_sizes)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,

@@ -1,5 +1,6 @@
 """Where JAX keeps its persistent compilation cache, how many programs the
-process has compiled, and which attention path its traces took."""
+process has compiled, and which attention and expert-layer paths its
+traces took."""
 
 from __future__ import annotations
 
@@ -45,7 +46,10 @@ def compile_count() -> int:
 
 
 ATTENTION_PATH = "/repro/attention_path/"
-_PATHS = {}
+MOE_PATH = "/repro/moe_path/"
+_PATHS = {ATTENTION_PATH: {"flash": 0, "dense": 0},
+          MOE_PATH: {"grouped": 0, "capacity": 0}}
+_LISTENING = []
 
 
 def record_attention_path(path: str, layers: int) -> None:
@@ -54,17 +58,35 @@ def record_attention_path(path: str, layers: int) -> None:
     jax.monitoring.record_scalar(ATTENTION_PATH + path, layers)
 
 
+def record_moe_path(path: str, layers: int) -> None:
+    """Note, at trace time, that ``layers`` expert layers took ``path``
+    (``grouped``: dropless, sorted by expert through a grouped matmul;
+    ``capacity``: the capacity dispatch)."""
+    jax.monitoring.record_scalar(MOE_PATH + path, layers)
+
+
+def _paths(prefix: str) -> dict:
+    if not _LISTENING:
+        _LISTENING.append(True)
+
+        def count(name, value, **kw):
+            for pre, got in _PATHS.items():
+                if name.startswith(pre):
+                    path = name[len(pre):]
+                    got[path] = got.get(path, 0) + int(value)
+
+        jax.monitoring.register_scalar_listener(count)
+    return dict(_PATHS[prefix])
+
+
 def attention_paths() -> dict:
     """Attention layers traced so far in this process, by path: a trace of
     a scanned stack counts each of its layers.  Like ``compile_count``,
-    the first call registers the listener and returns zeros."""
-    if not _PATHS:
-        _PATHS.update(flash=0, dense=0)
+    the first call registers the listener (counting from then on)."""
+    return _paths(ATTENTION_PATH)
 
-        def count(name, value, **kw):
-            if name.startswith(ATTENTION_PATH):
-                path = name[len(ATTENTION_PATH):]
-                _PATHS[path] = _PATHS.get(path, 0) + int(value)
 
-        jax.monitoring.register_scalar_listener(count)
-    return dict(_PATHS)
+def moe_paths() -> dict:
+    """Expert layers traced so far in this process, by path, counted as
+    ``attention_paths`` counts."""
+    return _paths(MOE_PATH)

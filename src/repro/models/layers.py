@@ -21,7 +21,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import FULL_ATTENTION, ModelConfig
 from repro.core import jax_compat as compat
-from repro.launch.compile_cache import record_attention_path
+from repro.launch.compile_cache import record_attention_path, record_moe_path
 from repro.launch.sharding import BATCH, MODEL, heads_ax, seq_ax, shard
 
 NEG_INF = -2.0e38
@@ -94,14 +94,21 @@ def rms_norm(x, p, eps):
 # ---------------------------------------------------------------------------
 # RoPE
 # ---------------------------------------------------------------------------
-def rope(x, positions, theta):
-    """x: (..., L, H, Dh), positions: (..., L) int, theta: scalar."""
+def rope(x, positions, theta, yarn=None):
+    """x: (..., L, H, Dh), positions: (..., L) int, theta: scalar.
+    ``yarn`` (static ``configs.base.Yarn``, with a static ``theta``)
+    rescales the frequencies and scales cos and sin."""
     dh = x.shape[-1]
     half = dh // 2
-    freq = jnp.arange(half, dtype=jnp.float32) * (2.0 / dh)
-    inv = jnp.power(jnp.asarray(theta, jnp.float32), -freq)  # (half,)
+    if yarn is None:
+        freq = jnp.arange(half, dtype=jnp.float32) * (2.0 / dh)
+        inv = jnp.power(jnp.asarray(theta, jnp.float32), -freq)  # (half,)
+    else:
+        inv = jnp.asarray(yarn.inv_freq(theta, dh), jnp.float32)
     ang = positions[..., None].astype(jnp.float32) * inv  # (..., L, half)
     sin, cos = jnp.sin(ang)[..., None, :], jnp.cos(ang)[..., None, :]  # (..., L, 1, half)
+    if yarn is not None:
+        sin, cos = sin * yarn.scale, cos * yarn.scale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -250,20 +257,22 @@ def _sdpa_decode(cfg: ModelConfig, q, k, v, mask):
     return out.reshape(b, lq, h, dh)
 
 
-def _sdpa_flash(q, k, v):
+def _sdpa_flash(q, k, v, window=FULL_ATTENTION):
     """Causal self-attention through the Pallas flash kernel (scores stay
-    in VMEM).  q: (B,L,H,Dh), k/v: (B,L,KV,Dh) → (B,L,H,Dh)."""
+    in VMEM), with a static sliding ``window`` or none.  q: (B,L,H,Dh),
+    k/v: (B,L,KV,Dh) → (B,L,H,Dh)."""
     from repro.kernels import ops
 
     out = ops.flash_attention(q.swapaxes(1, 2), k.swapaxes(1, 2),
-                              v.swapaxes(1, 2))
+                              v.swapaxes(1, 2), window=window)
     return out.swapaxes(1, 2)
 
 
 def _takes_flash(cfg: ModelConfig, plain_causal: bool) -> bool:
     """Whether causal self-attention takes the flash kernel: only where its
-    semantics are exactly the kernel's (positions 0..L-1 and no window,
-    which the caller certifies with ``plain_causal``; no logit softcap),
+    semantics are exactly the kernel's (positions 0..L-1 and a static
+    window or none, which the caller certifies with ``plain_causal``; no
+    logit softcap),
     where this device holds whole heads (no cp, no TP, no auto-partitioned
     mesh axis larger than 1) and where the kernel compiles (a chip, not
     interpret mode).  Everything else keeps the jnp ``_sdpa``."""
@@ -273,25 +282,33 @@ def _takes_flash(cfg: ModelConfig, plain_causal: bool) -> bool:
             or cfg.sharding_mode == "cp" or cfg.tp_degree > 1
             or _current_tp() is not None):
         return False
+    return _unpartitioned() and not ops.default_interpret()
+
+
+def _unpartitioned() -> bool:
+    """Whether no auto-partitioned mesh axis larger than 1 is in scope:
+    what the layer computes here is this device's whole share."""
     mesh = compat.get_abstract_mesh()
     if mesh is not None and not mesh.empty:
         manual = compat.manual_axis_names(mesh)
         if any(n > 1 for a, n in dict(mesh.shape).items()
                if a not in manual):
             return False
-    return not ops.default_interpret()
+    return True
 
 
 def attention(p, cfg: ModelConfig, x, positions, window, theta,
               cache=None, cache_pos=None, memory=None, causal=True,
-              collect_cache=False, plain_causal=False, stacked=1):
+              collect_cache=False, plain_causal=False, stacked=1,
+              yarn=None):
     """One attention sub-layer.
 
     Training: ``cache is None`` — full-sequence causal (+sliding window) attn;
               with ``collect_cache`` the full-sequence (k, v) are returned as
               a populated decode cache (prefill).  ``plain_causal`` (static)
-              says that ``positions`` are 0..L-1 and that no layer of the
-              stack has a window: see ``_takes_flash``.
+              says that ``positions`` are 0..L-1 and that ``window`` is a
+              static int: see ``_takes_flash``.  ``yarn`` (static) rescales
+              RoPE (``rope``).
     Decode:   ``cache`` holds (k, v) of length S; x has Lq=1; ``cache_pos`` is
               the write position.  Returns (out, new_cache).
     Cross-attention: ``memory`` is the encoder output; no cache, no causality.
@@ -318,12 +335,12 @@ def attention(p, cfg: ModelConfig, x, positions, window, theta,
             """One head-block's full attention subgraph: qkv slice → rope
             → sdpa over its heads → out-projection PARTIAL."""
             q, k, v = _qkv(p_, cfg, xx, xx)
-            q = rope(q, positions, theta)
-            k = rope(k, positions, theta)
+            q = rope(q, positions, theta, yarn)
+            k = rope(k, positions, theta, yarn)
             q = shard(q, BATCH, seq_ax(cfg), heads_ax(cfg), None)
             k = shard(k, BATCH, seq_ax(cfg), heads_ax(cfg), None)
             if flash:
-                out = _sdpa_flash(q, k, v)
+                out = _sdpa_flash(q, k, v, window)
             elif (isinstance(window, int) and window > 0 and causal
                     and lq % window == 0 and lq // window >= 2):
                 # static sliding window ⇒ block-banded attention: each q
@@ -364,8 +381,8 @@ def attention(p, cfg: ModelConfig, x, positions, window, theta,
         pos = cache_pos
         ragged = hasattr(pos, "ndim") and pos.ndim == 1
         pos_b = pos[:, None] if ragged else jnp.full((b, lq), pos, jnp.int32)
-        q = rope(q, pos_b, theta)
-        k = rope(k, pos_b, theta)
+        q = rope(q, pos_b, theta, yarn)
+        k = rope(k, pos_b, theta, yarn)
         if ragged:  # per-row scatter write (continuous batching)
             rows = jnp.arange(b)
             ck = cache["k"].at[rows, pos].set(k[:, 0].astype(cache["k"].dtype))
@@ -466,7 +483,7 @@ def _paged_gather(cache, block_tables, dtype):
 
 
 def attention_paged(p, cfg: ModelConfig, x, positions, window, theta,
-                    cache, block_tables, use_kernel=False):
+                    cache, block_tables, use_kernel=False, yarn=None):
     """Attention over a paged KV cache — decode (C=1) and chunked prefill
     (C>1) through ONE code path.
 
@@ -484,8 +501,8 @@ def attention_paged(p, cfg: ModelConfig, x, positions, window, theta,
     q, k, v = _qkv(p, cfg, x, x)
     b, c = x.shape[0], x.shape[1]
     pc = jnp.maximum(positions, 0)
-    q = rope(q, pc, theta)
-    k = rope(k, pc, theta)
+    q = rope(q, pc, theta, yarn)
+    k = rope(k, pc, theta, yarn)
     new_cache = _paged_write(cache, block_tables, positions, k, v)
 
     h, dh = q.shape[2], q.shape[3]
@@ -554,12 +571,18 @@ def mlp(p, cfg: ModelConfig, x):
 
 
 # ---------------------------------------------------------------------------
-# Mixture of Experts — capacity-based scatter/gather dispatch (no T×E×C
-# one-hot: see DESIGN.md §3).  Experts are sharded over the "model" axis.
+# Mixture of Experts.  Where the layer runs alone on its device (no
+# partitioned mesh axis, no "model" axis), the dropless grouped path:
+# every assignment to an expert held here is computed.  Under a "model"
+# mesh axis, capacity-based scatter/gather dispatch (no T×E×C one-hot: see
+# DESIGN.md §3), experts sharded over "model".
 # ---------------------------------------------------------------------------
+MOE_SCOPE = "moe.experts"
+
+
 def init_moe(key, cfg: ModelConfig):
     d, f = cfg.d_model, cfg.expert_d_ff
-    e = cfg.num_experts_padded  # dummy experts: zero weights, never routed
+    e = cfg.experts_held  # dummy experts: zero weights, never routed
     pdt = _dtype(cfg, "param")
     ks = jax.random.split(key, 5)
     p = {
@@ -576,16 +599,14 @@ def init_moe(key, cfg: ModelConfig):
 def _route(p, cfg: ModelConfig, xt, e_pad, cap):
     """Shared routing math.  xt: (T, D) → (flat_idx, slot, keep, gate, aux)."""
     t = xt.shape[0]
-    e, k = cfg.num_experts, cfg.top_k
+    k = cfg.top_k
     logits = (xt @ p["router"]).astype(jnp.float32)  # (T, E) active experts
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, idx = jax.lax.top_k(probs, k)  # (T, k), idx < E ≤ E_pad
     gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
 
     onehot = jax.nn.one_hot(idx, e_pad, dtype=jnp.float32)  # (T, k, E_pad)
-    f_e = jnp.mean(jnp.sum(onehot[..., :e], axis=1), axis=0)
-    p_e = jnp.mean(probs, axis=0)
-    aux = e * jnp.sum(f_e * p_e) * cfg.router_aux_coef
+    aux = _balance_loss(cfg, probs, idx)
 
     flat_idx = idx.reshape(t * k)
     flat_gate = gate_vals.reshape(t * k)
@@ -728,19 +749,148 @@ def _fit_batch_axes(mesh, b, candidates):
     return tuple(axes)
 
 
-def moe(p, cfg: ModelConfig, x):
-    """x: (B, L, D) → (out, aux_loss).  Dispatches to the expert-parallel
-    shard_map path under a mesh with a "model" axis (and enough tokens),
-    else the dense reference path."""
+def _balance_loss(cfg: ModelConfig, probs, idx):
+    """Switch-style balance loss over all ``num_experts``: E · Σ_e f_e·p_e
+    · ``router_aux_coef`` (f_e: the share of assignments to e, p_e: its
+    mean router probability); zero where the coefficient is."""
+    if not cfg.router_aux_coef:
+        return jnp.zeros((), jnp.float32)
+    e = cfg.num_experts
+    f_e = jnp.mean(jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.float32),
+                           axis=1), axis=0)
+    return e * jnp.sum(f_e * jnp.mean(probs, axis=0)) * cfg.router_aux_coef
+
+
+@jax.custom_vjp
+def _dispatch(xt, order, inv, mine):
+    """Token rows in the order of the sorted assignments: (T, D) →
+    (T·k, D), row r = token ``order[r] // k``.  The backward gathers
+    back through ``inv`` (no scatter) and sums each token's k rows,
+    counting only the assignments held here (``mine``: (T, k))."""
+    return xt[order // mine.shape[1]]
+
+
+def _dispatch_fwd(xt, order, inv, mine):
+    return _dispatch(xt, order, inv, mine), (inv, mine)
+
+
+def _dispatch_bwd(res, g):
+    inv, mine = res
+    gu = jnp.where(mine.reshape(-1, 1), g[inv], 0).astype(g.dtype)
+    return gu.reshape(mine.shape + g.shape[-1:]).sum(axis=1), None, None, \
+        None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _permute(x, to, back):
+    """``x[to]`` for a permutation ``to`` whose inverse is ``back``; the
+    backward is the gather ``g[back]``."""
+    return x[to]
+
+
+def _permute_fwd(x, to, back):
+    return x[to], (to, back)
+
+
+def _permute_bwd(res, g):
+    return g[res[1]], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _takes_gmm() -> bool:
+    """Whether the grouped matmuls run as the Pallas kernels (compiled,
+    on a chip); elsewhere ``jax.lax.ragged_dot`` computes the same."""
+    from repro.kernels import ops
+
+    return not ops.default_interpret()
+
+
+def _grouped_matmul(xs, w, sizes):
+    """Rows of group g (held expert g, in order, ``sizes[g]`` rows) times
+    ``w[g]``; rows after the held groups (``sizes[-1]`` of them) are left
+    unspecified."""
+    if _takes_gmm():
+        from repro.kernels import ops
+
+        return ops.grouped_matmul(xs, w, sizes)
+    return jax.lax.ragged_dot(xs, w, sizes[:-1])
+
+
+def _moe_grouped(p, cfg: ModelConfig, x):
+    """Dropless expert layer over the experts held here
+    (``cfg.held_experts`` from ``cfg.first_held_expert``; all by default):
+    the router's product in f32 over all ``num_experts``, softmax, top-k,
+    gates renormalised; the assignments to held experts sorted by expert
+    and run through grouped matmuls whose work follows the rows routed to
+    them (no capacity, no drop, no padded expert); the gate-weighted
+    outputs summed back per token in f32.  Assignments to experts held
+    elsewhere contribute nothing here.  ``cfg.frozen_router``: the gates
+    carry no gradient.  Returns (out, aux)."""
+    b, l, d = x.shape
+    t, k = b * l, cfg.top_k
+    held, first = cfg.experts_held, cfg.first_held_expert
+    xt = x.reshape(t, d)
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if cfg.frozen_router:
+        logits = jax.lax.stop_gradient(logits)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, idx = jax.lax.top_k(probs, k)
+    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    aux = _balance_loss(cfg, probs, idx)
+
+    local = idx.reshape(t * k) - first
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held)  # held elsewhere: one last group
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)
+
+    xs = _dispatch(xt, order, inv, mine.reshape(t, k))
+    h = _act(cfg.act)(_grouped_matmul(xs, p["w_gate"], sizes)) \
+        * _grouped_matmul(xs, p["w_up"], sizes)
+    ys = _permute(_grouped_matmul(h, p["w_down"], sizes), inv, order)
+    ys = jnp.where(mine[:, None], ys, 0).reshape(t, k, d)
+    out = jnp.sum(ys.astype(jnp.float32) * gate[..., None], axis=1)
+    return out.astype(x.dtype).reshape(b, l, d), aux
+
+
+def moe(p, cfg: ModelConfig, x, stacked: int = 1):
+    """x: (B, L, D) → (out, aux_loss).  Where the layer runs alone on its
+    device (``_unpartitioned``, no "model" axis larger than 1) the
+    dropless grouped path; else, under a mesh with a "model" axis (and
+    enough tokens), the expert-parallel shard_map path, or the capacity
+    dispatch.  Records its path for the ``stacked`` layers this trace
+    stands for (``compile_cache.moe_paths``)."""
     mesh = compat.get_abstract_mesh()
-    use_ep = (mesh is not None and not mesh.empty
-              and "model" in mesh.axis_names
-              and cfg.num_experts_padded % _axsize(mesh, "model") == 0
-              and x.shape[0] * x.shape[1] >= 4096)
-    if use_ep:
-        out, aux = _moe_ep(p, cfg, x, mesh)
-    else:
-        out, aux = _moe_dense(p, cfg, x)
+    meshed = mesh is not None and not mesh.empty
+    with jax.named_scope(MOE_SCOPE):
+        if _unpartitioned() and (not meshed
+                                 or _axsize(mesh, "model") == 1):
+            record_moe_path("grouped", stacked)
+            out, aux = _moe_grouped(p, cfg, x)
+        else:
+            if cfg.held_experts is not None or cfg.frozen_router:
+                raise ValueError(
+                    f"{cfg.name}: a share of the experts and a frozen "
+                    f"router run only where the layer runs alone on its "
+                    f"device")
+            record_moe_path("capacity", stacked)
+            use_ep = (meshed and "model" in mesh.axis_names
+                      and cfg.num_experts_padded
+                      % _axsize(mesh, "model") == 0
+                      and x.shape[0] * x.shape[1] >= 4096)
+            if use_ep:
+                out, aux = _moe_ep(p, cfg, x, mesh)
+            else:
+                out, aux = _moe_dense(p, cfg, x)
     if "shared" in p:
         out = out + mlp(p["shared"], cfg, x)
     return out, aux

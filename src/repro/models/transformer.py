@@ -52,20 +52,21 @@ def _apply_layer(p, cfg, spec, h, positions, window, theta, cache, cache_pos,
                  memory, causal=True, collect_cache=False, block_tables=None,
                  paged_kernel=False, plain_causal=False, stacked=1):
     """One (mixer → [cross] → ffn) layer. Returns (h, new_cache, aux).
-    ``plain_causal`` and ``stacked`` are static: see ``L.attention``."""
+    ``plain_causal`` and ``stacked`` are static: see ``L.attention``; so
+    is ``spec.rope_yarn``."""
     aux = jnp.zeros((), jnp.float32)
     x = L.rms_norm(h, p["pre_norm"], cfg.norm_eps)
     if spec.mixer == "attn":
         if block_tables is not None:
             out, new_cache = L.attention_paged(
                 p["attn"], cfg, x, positions, window, theta, cache,
-                block_tables, use_kernel=paged_kernel)
+                block_tables, use_kernel=paged_kernel, yarn=spec.rope_yarn)
         else:
             out, new_cache = L.attention(
                 p["attn"], cfg, x, positions, window, theta, cache=cache,
                 cache_pos=cache_pos, causal=causal,
                 collect_cache=collect_cache, plain_causal=plain_causal,
-                stacked=stacked)
+                stacked=stacked, yarn=spec.rope_yarn)
     elif spec.mixer == "mamba":
         out, new_cache = S.mamba(p["mamba"], cfg, x, cache=cache,
                                  collect_cache=collect_cache)
@@ -88,7 +89,7 @@ def _apply_layer(p, cfg, spec, h, positions, window, theta, cache, cache_pos,
     if spec.ffn != "none":
         x = L.rms_norm(h, p["ffn_norm"], cfg.norm_eps)
         if spec.ffn == "moe":
-            out, aux = L.moe(p["moe"], cfg, x)
+            out, aux = L.moe(p["moe"], cfg, x, stacked=stacked)
         else:
             out = L.mlp(p["mlp"], cfg, x)
         h = h + out
@@ -147,70 +148,132 @@ def init_model(key, cfg: ModelConfig):
 def _run_stack(params, cfg: ModelConfig, h, positions, cache, cache_pos,
                memory, remat=False, collect_cache=False, block_tables=None,
                paged_kernel=False, arange_positions=False):
-    """``arange_positions`` (static): ``positions`` are 0..L-1 in every
-    row, as ``forward`` and ``prefill`` make them."""
-    specs, repeat = cfg.superblock()
-    np_windows, np_thetas = cfg.layer_windows()  # (repeat, S) numpy arrays
-    windows = jnp.asarray(np_windows)
-    thetas = jnp.asarray(np_thetas)
-    # causal attention with nothing a flash kernel would not know: decided
-    # here, before the scan turns the windows into traced values
-    plain_causal = arange_positions and bool(
-        (np_windows == FULL_ATTENTION).all())
-    stacked = repeat if cfg.scan_layers else 1
+    """Every decoder stack, one period at a time.  A period is the
+    superblock (``cfg.superblock()``; parameters and cache
+    ``{str(i): (repeat, ...)}``) or, for a uniform stack whose windows
+    repeat (``cfg.window_pattern()``), P layers of its superblock of one,
+    the (layers, ...) stack viewed as (layers // P, P, ...).  Each
+    position's layer kind, window and YaRN are static (closed over), so a
+    windowed layer reaches ``L.attention`` with its window and takes the
+    flash kernel where ``L._takes_flash`` holds.  Whole periods are
+    scanned (``cfg.scan_layers``); layers past the last whole period, and
+    every period when unscanned (exact cost_analysis for the dry-run), run
+    after the scan with a static θ.  The scan of a superblock takes θ as a
+    scanned value, one per layer, though each is its spec's: that keeps
+    the step of the stacks without windows (qwen2's) the one it was,
+    instruction for instruction.  Remat: the superblock whole, or each
+    layer of a window period.  ``arange_positions`` (static):
+    ``positions`` are 0..L-1 in every row, as ``forward`` and ``prefill``
+    make them."""
+    pattern = cfg.window_pattern()
+    per_layer = pattern is not None
+    if not per_layer:
+        specs, n_periods = cfg.superblock()
+        stack, layer_cache = params["stack"], cache
+        whole = n_periods
 
-    def superblock_body(carry, xs):
-        h, aux_acc = carry
-        p_sb, win_sb, th_sb, cache_sb = xs
-        new_cache_sb = {}
-        for i, spec in enumerate(specs):
-            c_i = cache_sb[str(i)] if cache_sb is not None else None
-            h, nc, aux = _apply_layer(
-                p_sb[str(i)], cfg, spec, h, positions, win_sb[i], th_sb[i],
-                c_i, cache_pos, memory, collect_cache=collect_cache,
-                block_tables=block_tables, paged_kernel=paged_kernel,
-                plain_causal=plain_causal, stacked=stacked)
-            new_cache_sb[str(i)] = nc if nc is not None else {}
-        return (h, aux_acc + aux), new_cache_sb
+        def to_sb(tree):
+            return tree
 
-    if remat:
-        if cfg.save_moe_a2a:
-            # save the named MoE a2a results across the remat boundary:
-            # −2 a2a/layer of wire, +~2.7 GB/layer of HBM (see §Perf it. 2)
-            policy = jax.checkpoint_policies.save_only_these_names(
-                "moe_dispatch", "moe_combine")
-            body = jax.checkpoint(superblock_body, policy=policy)
-        else:
-            body = jax.checkpoint(superblock_body)
+        def from_sb(nc):
+            return nc
+
+        def rows(r):  # the stack's rows of period r
+            return r
     else:
-        body = superblock_body
+        specs, n = pattern, cfg.num_layers
+        period = len(specs)
+        whole, n_periods = n // period, -(-n // period)
+        stack = params["stack"]["0"]
+        layer_cache = cache["0"] if cache is not None else None
 
-    if cfg.scan_layers:
-        (h, aux), new_cache = jax.lax.scan(
-            body, (h, jnp.zeros((), jnp.float32)),
-            (params["stack"], windows, thetas, cache))
-    else:  # unrolled: exact cost_analysis for the dry-run.  Window/theta are
-        # STATIC python scalars (closed over, NOT traced) so sliding-window
-        # layers take the block-banded attention path (compute ∝ window).
-        carry = (h, jnp.zeros((), jnp.float32))
-        collected = []
-        for r in range(repeat):
-            p_r = jax.tree.map(lambda x: x[r], params["stack"])
-            c_r = jax.tree.map(lambda x: x[r], cache) if cache is not None else None
-            win_r = tuple(int(w) for w in np_windows[r])
-            th_r = tuple(float(t) for t in np_thetas[r])
+        def to_sb(tree):  # (count, ...) → the superblock's {str(i): ...}
+            if tree is None:
+                return None
+            count = jax.tree.leaves(tree)[0].shape[0]
+            return {str(i): jax.tree.map(lambda x: x[i], tree)
+                    for i in range(count)}
 
-            def body_r(carry, pc, _w=win_r, _t=th_r):
-                return superblock_body(carry, (pc[0], _w, _t, pc[1]))
+        def from_sb(nc):
+            return {"0": jax.tree.map(lambda *c: jnp.stack(c),
+                                      *nc.values())}
 
-            body_r = jax.checkpoint(body_r) if remat else body_r
-            carry, nc = body_r(carry, (p_r, c_r))
-            collected.append(nc)
-        h, aux = carry
-        new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *collected) \
-            if collected and (cache is not None or collect_cache) else None
-    if cache is None and not collect_cache:
-        new_cache = None
+        def rows(r):
+            return slice(r * period, min(n, (r + 1) * period))
+    if not cfg.scan_layers:
+        whole = 0
+    # save the named MoE a2a results across the remat boundary: −2
+    # a2a/layer of wire, +~2.7 GB/layer of HBM (see §Perf it. 2)
+    policy = jax.checkpoint_policies.save_only_these_names(
+        "moe_dispatch", "moe_combine") if cfg.save_moe_a2a else None
+
+    def layer(i, stacked):
+        def apply(carry, p_l, theta, c_l):
+            h, aux_acc = carry
+            h, nc, aux = _apply_layer(
+                p_l, cfg, specs[i], h, positions, specs[i].window, theta,
+                c_l, cache_pos, memory, collect_cache=collect_cache,
+                block_tables=block_tables, paged_kernel=paged_kernel,
+                plain_causal=arange_positions, stacked=stacked)
+            return (h, aux_acc + aux), (nc if nc is not None else {})
+        # a window period's θ is static (its spec's)
+        return jax.checkpoint(apply, policy=policy, static_argnums=(2,)) \
+            if remat and per_layer else apply
+
+    def period_fn(stacked):
+        """One period, or the first positions of one (those ``xs``'
+        parameters hold): xs (parameters, θ per position or None for the
+        specs' static θ, cache)."""
+        fs = [layer(i, stacked) for i in range(len(specs))]
+
+        def run(carry, xs):
+            p_sb, th, c_sb = xs
+            new = {}
+            for i, f in enumerate(fs[:len(p_sb)]):
+                carry, new[str(i)] = f(
+                    carry, p_sb[str(i)],
+                    specs[i].rope_theta if th is None else th[i],
+                    c_sb[str(i)] if c_sb is not None else None)
+            return carry, new
+        return jax.checkpoint(run, policy=policy) \
+            if remat and not per_layer else run
+
+    def at(tree, r):
+        return None if tree is None else jax.tree.map(lambda x: x[r], tree)
+
+    carry = (h, jnp.zeros((), jnp.float32))
+    collected = []  # new caches, each (layers, ...) of the stack
+    if whole:
+        scanned = period_fn(whole)
+
+        def body(carry, xs):
+            p_per, th, c_per = xs
+            carry, nc = scanned(carry, (to_sb(p_per), th, to_sb(c_per)))
+            return carry, from_sb(nc)
+
+        def periods(x):  # (layers, ...) → (whole, P, ...)
+            return x[:whole * period].reshape(
+                (whole, period) + x.shape[1:]) if per_layer else x
+        thetas = None if per_layer else jnp.asarray(
+            [[s.rope_theta for s in specs]] * whole, jnp.float32)
+        carry, nc = jax.lax.scan(
+            body, carry, (jax.tree.map(periods, stack), thetas,
+                          jax.tree.map(periods, layer_cache)))
+        collected.append(jax.tree.map(
+            lambda x: x.reshape((-1,) + x.shape[2:]), nc)
+            if per_layer else nc)
+    for r in range(whole, n_periods):
+        carry, nc = period_fn(1)(
+            carry, (to_sb(at(stack, rows(r))), None,
+                    to_sb(at(layer_cache, rows(r)))))
+        nc = from_sb(nc)
+        collected.append(nc if per_layer
+                         else jax.tree.map(lambda x: x[None], nc))
+    h, aux = carry
+    new_cache = None
+    if cache is not None or collect_cache:
+        new_cache = collected[0] if len(collected) == 1 else jax.tree.map(
+            lambda *c: jnp.concatenate(c), *collected)
     return h, aux, new_cache
 
 

@@ -61,12 +61,14 @@ def test_gemma_window_pattern():
     from repro.configs import get_config
 
     cfg = get_config("gemma3-1b")
-    windows, thetas = cfg.layer_windows()
-    assert windows.shape == (26, 1)
-    globals_ = [i for i in range(26) if windows[i, 0] == -1]
+    pattern = cfg.window_pattern()
+    assert cfg.num_layers == 26 and len(pattern) == 6
+    windows = [pattern[i % 6].window for i in range(26)]
+    thetas = [pattern[i % 6].rope_theta for i in range(26)]
+    globals_ = [i for i in range(26) if windows[i] == -1]
     assert globals_ == [5, 11, 17, 23]
-    assert all(windows[i, 0] == 512 for i in range(26) if i not in globals_)
-    assert thetas[5, 0] == 1_000_000.0 and thetas[0, 0] == 10_000.0
+    assert all(windows[i] == 512 for i in range(26) if i not in globals_)
+    assert thetas[5] == 1_000_000.0 and thetas[0] == 10_000.0
 
 
 # ---------------------------------------------------------------------------
@@ -112,7 +114,7 @@ def _cross_attention():
 
 @pytest.mark.parametrize("case,paths", [
     ("qwen2", {"flash": 2, "dense": 0}),
-    ("sliding_window", {"flash": 0, "dense": 2}),
+    ("sliding_window", {"flash": 2, "dense": 0}),
     ("cp", {"flash": 0, "dense": 2}),
     ("given_positions", {"flash": 0, "dense": 2}),
     ("cross_attention", {"flash": 0, "dense": 1}),
@@ -121,8 +123,9 @@ def _cross_attention():
 def test_attention_path_dispatch(case, paths, request):
     """Tracing a loss (or one cross-attention call) records one path per
     attention layer, and only the kernel's exact case takes the kernel:
-    arange positions, no window, whole heads, compiled kernels.  Tracing
-    only: nothing is lowered."""
+    arange positions, a static window or none (gemma3's local and global
+    layers), whole heads, compiled kernels.  Tracing only: nothing is
+    lowered."""
     import re
 
     from repro.launch.compile_cache import attention_paths
@@ -144,9 +147,13 @@ def test_attention_path_dispatch(case, paths, request):
     text = str(jax.make_jaxpr(fn)(*args))
     after = attention_paths()
     assert {k: after[k] - before[k] for k in paths} == paths
-    kernels = set(re.findall(r"flash_attention_(?:fwd|dq|dkv)", text))
+    kernels = set(re.findall(r"flash_attention_(?:window_)?(?:fwd|dq|dkv)",
+                             text))
     want = {"flash_attention_fwd", "flash_attention_dq",
             "flash_attention_dkv"} if paths["flash"] else set()
+    if case == "sliding_window":
+        want |= {"flash_attention_window_fwd", "flash_attention_window_dq",
+                 "flash_attention_window_dkv"}
     assert kernels == want
 
 

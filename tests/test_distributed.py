@@ -151,3 +151,33 @@ def test_compressed_pod_exchange_lowers_and_reduces_wire():
         print(f"EXCHANGE_OK ratio={ratio:.1f}")
     """)
     assert "EXCHANGE_OK" in out
+
+
+def test_gather_chunks_moves_each_chunk_to_its_worker():
+    """``ShardComm.gather_chunks`` on 4 devices: worker w receives every
+    peer's chunk w, stacked by peer, whether 128 divides the chunk (the
+    wire then moves (W, ..., C/128, 128)) or not."""
+    out = _run("""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import PartitionSpec as P
+        from repro.core.comm import ShardComm
+        from repro.core.jax_compat import make_mesh, shard_map
+
+        W = 4
+        mesh = make_mesh((W,), ("pod",))
+        comm = ShardComm("pod", W)
+        for c in (256, 100):
+            x = jnp.arange(W * 3 * W * c, dtype=jnp.float32).reshape(
+                W, 3, W * c).astype(jnp.bfloat16)
+            f = shard_map(lambda v: comm.gather_chunks(v[0])[None], mesh,
+                          in_specs=P("pod"), out_specs=P("pod"))
+            got = np.asarray(jax.jit(f)(x).astype(jnp.float32))
+            xs = np.asarray(x.astype(jnp.float32))
+            want = np.stack([np.stack([xs[p, :, w * c:(w + 1) * c]
+                                       for p in range(W)])
+                             for w in range(W)])
+            assert got.shape == (W, W, 3, c), got.shape
+            assert np.array_equal(got, want), c
+        print("GATHER_OK")
+    """, devices=4)
+    assert "GATHER_OK" in out

@@ -159,3 +159,36 @@ def test_layer_grad_holds_no_score_square(one_chip, compiled_kernels):
 
     text = _compile(jax.grad(layer, argnums=(0, 1)), params, h)
     assert f"{H},{L_CELL},{L_CELL}]" not in text
+
+
+def test_mellum2_period_compiles_with_its_kernels(one_chip, compiled_kernels):
+    """One period of Mellum2 (three 1024-token window layers, one full
+    layer with YaRN) at its published widths, 8 of its 64 experts held,
+    loss and gradients in bf16 over 1 × 2048 tokens: the plain and the
+    window flash kernels and the grouped matmuls are in the program, and
+    no (B, H, L, L) buffer is."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import transformer as T
+    from repro.train.losses import lm_loss
+
+    cfg = dataclasses.replace(
+        get_config("mellum2-12b-a2.5b").with_depth(4).with_expert_share(8),
+        vocab_size=1024, param_dtype="bfloat16", compute_dtype="bfloat16")
+    params = jax.eval_shape(lambda: T.init_model(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), params)
+    toks = _sds((1, L_CELL), jnp.int32, one_chip)
+
+    def loss(p, t):
+        logits, aux = T.forward(p, cfg, tokens=t, remat=True)
+        return lm_loss(logits, t, aux)
+
+    text = _compile(jax.grad(loss), params, toks)
+    kernels = {line.split(" = ")[0].strip().lstrip("%").split(".")[0]
+               for line in text.splitlines() if "tpu_custom_call" in line}
+    assert {"flash_attention_fwd", "flash_attention_dq",
+            "flash_attention_dkv", "flash_attention_window_fwd",
+            "flash_attention_window_dq", "flash_attention_window_dkv",
+            "expert_gmm", "expert_tgmm"} <= kernels, kernels
+    assert f"32,{L_CELL},{L_CELL}]" not in text
