@@ -15,6 +15,8 @@ f32 (tests/test_precision.py) without any per-layer dtype plumbing.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
@@ -578,6 +580,8 @@ def mlp(p, cfg: ModelConfig, x):
 # DESIGN.md §3), experts sharded over "model".
 # ---------------------------------------------------------------------------
 MOE_SCOPE = "moe.experts"
+# the grouped path's two ways, inside MOE_SCOPE (``_held_rows``)
+COMPACT_SCOPE, SPILL_SCOPE = "moe.compact", "moe.spill"
 
 
 def init_moe(key, cfg: ModelConfig):
@@ -821,6 +825,130 @@ def _grouped_matmul(xs, w, sizes):
     return jax.lax.ragged_dot(xs, w, sizes[:-1])
 
 
+def _compact_rows(rows: int, held: int, experts: int) -> int:
+    """Rows the compact path's buffers hold for ``rows`` assignments of
+    which ``held`` of ``experts`` are held here: twice the expected held
+    rows, in whole 512-row tiles of the grouped matmul, at most ``rows``
+    (then the compact path is no smaller, and the layer takes none)."""
+    tile = 512
+    return min(rows, -(-2 * rows * held // (experts * tile)) * tile)
+
+
+def _spill(act, xt, w_gate, w_up, w_down, gate, order, inv, mine, sizes):
+    """Every assignment's row through the grouped matmuls, in the sorted
+    order (T·k rows; the kernels skip those held elsewhere, and the
+    combine masks them to zero), summed back per token in f32: (T, D)."""
+    t, k = gate.shape
+    xs = _dispatch(xt, order, inv, mine.reshape(t, k))
+    h = act(_grouped_matmul(xs, w_gate, sizes)) \
+        * _grouped_matmul(xs, w_up, sizes)
+    ys = _permute(_grouped_matmul(h, w_down, sizes), inv, order)
+    ys = jnp.where(mine[:, None], ys, 0).reshape(t, k, -1)
+    return jnp.sum(ys.astype(jnp.float32) * gate[..., None], axis=1)
+
+
+def _compact(act, rows, xt, w_gate, w_up, w_down, gate, order, inv, mine,
+             sizes):
+    """``_spill``'s sum from the first ``rows`` sorted assignments alone,
+    where the held ones (the first n = Σ sizes[:-1]) fit: their rows and
+    products are the same, and the ``rows − n`` after them are a last
+    group that no kernel computes."""
+    t, k = gate.shape
+    sizes = sizes.at[-1].set(rows - jnp.sum(sizes[:-1]))
+    to = order[:rows]
+    back = jnp.where(mine, inv, 0)  # a held assignment's row, under n
+    xs = _dispatch(xt, to, back, mine.reshape(t, k))
+    h = act(_grouped_matmul(xs, w_gate, sizes)) \
+        * _grouped_matmul(xs, w_up, sizes)
+    return _combine(_grouped_matmul(h, w_down, sizes), gate, to, back, mine)
+
+
+@jax.custom_vjp
+def _combine(y, gate, to, back, mine):
+    """Σ_j gate[t, j] · y[back[t·k + j]] over the assignments held here
+    (``mine``), in f32: (rows, D) → (T, D).  The backward gathers the
+    output's gradient to the rows (row r: token ``to[r] // k``) times
+    their gates, never broadcasting it to all T·k assignments."""
+    t, k = gate.shape
+    ys = jnp.where(mine[:, None], y[back], 0).reshape(t, k, -1)
+    return jnp.sum(ys.astype(jnp.float32) * gate[..., None], axis=1)
+
+
+def _combine_fwd(y, gate, to, back, mine):
+    return _combine(y, gate, to, back, mine), (y, gate, to, back, mine)
+
+
+def _combine_bwd(res, g):
+    y, gate, to, back, mine = res
+    t, k = gate.shape
+    gy = (g[to // k] * gate.reshape(-1)[to][:, None]).astype(y.dtype)
+    ys = jnp.where(mine[:, None], y[back], 0).reshape(t, k, -1)
+    d_gate = jnp.sum(g[:, None, :] * ys.astype(jnp.float32), axis=-1)
+    return gy, d_gate, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _branches(act, rows):
+    """The compact and the spill path, each under its scope."""
+    def compact(*args):
+        with jax.named_scope(COMPACT_SCOPE):
+            return _compact(act, rows, *args)
+
+    def spill(*args):
+        with jax.named_scope(SPILL_SCOPE):
+            return _spill(act, *args)
+    return compact, spill
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_rows(act, rows, learn_gates, xt, w_gate, w_up, w_down, gate,
+               order, inv, mine, sizes):
+    """``_spill``'s result, through ``_compact`` where the held
+    assignments fit in ``rows``.  Forward and backward each choose on the
+    same count, and the residuals are the inputs and the routing alone:
+    the backward recomputes its branch's products, so no T·k-row buffer
+    outlives either pass.  ``learn_gates`` False: the gates get no
+    gradient (a frozen router's)."""
+    return _held_rows_fwd(act, rows, learn_gates, xt, w_gate, w_up, w_down,
+                          gate, order, inv, mine, sizes)[0]
+
+
+def _fits(rows, sizes):
+    return jnp.sum(sizes[:-1]) <= rows
+
+
+def _kept(fn):
+    """``fn`` with its results behind an optimization barrier, as a
+    branch of ``lax.cond``: XLA otherwise hoists the ops that end both
+    branches alike out of the conditional under one branch's scope, and a
+    trace counts them as that branch's work whichever ran."""
+    return lambda *args: jax.lax.optimization_barrier(fn(*args))
+
+
+def _held_rows_fwd(act, rows, learn_gates, *args):
+    out = jax.lax.cond(_fits(rows, args[-1]),
+                       *map(_kept, _branches(act, rows)), *args)
+    return out, args
+
+
+def _held_rows_bwd(act, rows, learn_gates, args, g):
+    def grads(fn):  # of xt, the three weights and the gates
+        def run(*ops):
+            _, vjp = jax.vjp(lambda *d: fn(*d, *ops[5:]), *ops[:5])
+            d = vjp(g)
+            return d if learn_gates else d[:4] + (jnp.zeros_like(d[4]),)
+        return run
+    compact, spill = _branches(act, rows)
+    d = jax.lax.cond(_fits(rows, args[-1]), _kept(grads(compact)),
+                     _kept(grads(spill)), *args)
+    return (*d, None, None, None, None)
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
 def _moe_grouped(p, cfg: ModelConfig, x):
     """Dropless expert layer over the experts held here
     (``cfg.held_experts`` from ``cfg.first_held_expert``; all by default):
@@ -830,7 +958,12 @@ def _moe_grouped(p, cfg: ModelConfig, x):
     them (no capacity, no drop, no padded expert); the gate-weighted
     outputs summed back per token in f32.  Assignments to experts held
     elsewhere contribute nothing here.  ``cfg.frozen_router``: the gates
-    carry no gradient.  Returns (out, aux)."""
+    carry no gradient.
+
+    Where a share of the experts is held, the row buffers hold the first
+    ``_compact_rows`` sorted assignments (``_compact``), and a count of
+    held assignments beyond them takes every row (``_spill``), so the
+    layer stays dropless.  Returns (out, aux)."""
     b, l, d = x.shape
     t, k = b * l, cfg.top_k
     held, first = cfg.experts_held, cfg.first_held_expert
@@ -853,12 +986,14 @@ def _moe_grouped(p, cfg: ModelConfig, x):
         jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
     sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)
 
-    xs = _dispatch(xt, order, inv, mine.reshape(t, k))
-    h = _act(cfg.act)(_grouped_matmul(xs, p["w_gate"], sizes)) \
-        * _grouped_matmul(xs, p["w_up"], sizes)
-    ys = _permute(_grouped_matmul(h, p["w_down"], sizes), inv, order)
-    ys = jnp.where(mine[:, None], ys, 0).reshape(t, k, d)
-    out = jnp.sum(ys.astype(jnp.float32) * gate[..., None], axis=1)
+    act = _act(cfg.act)
+    args = (xt, p["w_gate"], p["w_up"], p["w_down"], gate, order, inv, mine,
+            sizes)
+    rows = _compact_rows(t * k, held, cfg.num_experts)
+    if rows < t * k:
+        out = _held_rows(act, rows, not cfg.frozen_router, *args)
+    else:
+        out = _spill(act, *args)
     return out.astype(x.dtype).reshape(b, l, d), aux
 
 

@@ -371,3 +371,99 @@ def test_moe_path_is_recorded_per_layer():
         jax.eval_shape(lambda p, x: L.moe(p, cfg, x, stacked=3), p, x)
     assert moe_paths()["grouped"] - m0["grouped"] == 3
     assert moe_paths()["capacity"] == m0["capacity"]
+
+
+def _routed(case, d=64, e=8):
+    """A router and inputs for ``case``, over 2 held experts (0 and 1) of
+    8, top-2: ``typical`` (random), ``exact`` (every token picks exactly
+    one held expert: feature 0 is 1, feature 1 a sign that chooses
+    between them, and the others tie-break six experts held elsewhere,
+    all below the chosen held one and above the other), ``spill``
+    (every token picks both held experts)."""
+    x = jax.random.normal(jax.random.PRNGKey(11), (2, 1024, d))
+    router = 0.02 * jax.random.normal(jax.random.PRNGKey(12), (d, e))
+    if case == "typical":
+        return x, router
+    sign = jnp.sign(jax.random.normal(jax.random.PRNGKey(13), x.shape[:2]))
+    x = x.at[..., 0].set(1.0).at[..., 1].set(sign)
+    router = jnp.zeros((d, e)).at[2:, 2:].set(0.2 * jnp.eye(d - 2, e - 2))
+    if case == "exact":  # held: 8 or 2, elsewhere 6 + 0.2·x, x ~ N(0, 1)
+        router = router.at[0].set(jnp.array([5.0, 5.0] + [6.0] * (e - 2)))
+        return x, router.at[1, :2].set(jnp.array([3.0, -3.0]))
+    return x, router.at[0, :2].set(10.0)
+
+
+@pytest.mark.parametrize("case", ["typical", "exact", "spill", "kernels",
+                                  "all_held"])
+def test_compact_rows_equal_every_row_bit_for_bit(monkeypatch, case):
+    """2048 tokens, top-2, 2 of 8 experts held: the layer's row buffers
+    hold 2048 of the 4096 assignments.  Output and gradients (input and
+    every weight) equal the path that carries all 4096, bit for bit: where
+    the held assignments fit (fewer than 2048, or exactly 2048) the
+    compact branch runs, and where they do not (4096) the spill branch
+    keeps them all, as the capacity dispatch with room for all of them
+    does.  ``kernels``: typical routing through the grouped-matmul kernels
+    (interpreted), whose last group of ``rows − n`` rows is left unwritten.
+    A layer that holds every expert has no choice to make."""
+    held = 8 if case == "all_held" else 2
+    cfg = _moe_cfg().with_expert_share(held, 0)
+    x, router = _routed(case if case in ("exact", "spill") else "typical")
+    kernels = case == "kernels"
+    if kernels:
+        monkeypatch.setattr(L, "_takes_gmm", lambda: True)
+    p = dict(jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                          L.init_moe(jax.random.PRNGKey(14), cfg)),
+             router=router)
+    x = x.astype(jnp.bfloat16)
+    t, k = 2048, cfg.top_k
+    rows = L._compact_rows(t * k, held, cfg.num_experts)
+    weight = jax.random.normal(jax.random.PRNGKey(15), x.shape)
+
+    def run():
+        """(jaxpr, out, gradients), traced anew (jax caches traces by
+        function)."""
+        def loss(p, x):
+            out, aux = L._moe_grouped(p, cfg, x)
+            return jnp.sum(out.astype(jnp.float32) * weight) + aux, out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(p, x)
+        return str(jax.make_jaxpr(loss)(p, x)), out, grads
+
+    if case == "all_held":
+        assert L._compact_rows(t * k, held, cfg.num_experts) == t * k
+        assert " cond[" not in run()[0]
+        return
+    assert rows == 2048
+    idx = jax.lax.top_k(jnp.einsum("btd,de->bte", x.astype(jnp.float32),
+                                   router), k)[1]
+    n = int(jnp.sum(idx < held))
+    assert {"typical": n < rows, "kernels": n < rows, "exact": n == rows,
+            "spill": n == t * k}[case], n
+    # the branch that must not run returns NaN
+    monkeypatch.setattr(L, "_spill" if case != "spill" else "_compact",
+                        lambda *a: jnp.full((t, x.shape[-1]), jnp.nan))
+    jaxpr, out, grads = run()
+    assert kernels or " cond[" in jaxpr  # the interpreted kernels hold conds
+    monkeypatch.undo()
+    if kernels:
+        monkeypatch.setattr(L, "_takes_gmm", lambda: True)
+    monkeypatch.setattr(L, "_compact_rows", lambda r, h, e: r)
+    jaxpr, want, want_grads = run()
+    assert kernels or " cond[" not in jaxpr
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    for path, g in jax.tree_util.tree_flatten_with_path(grads)[0]:
+        ref = want_grads[path[0].idx]
+        for key in path[1:]:
+            ref = ref[key.key]
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(ref),
+                                      err_msg=str(path))
+    if case == "spill":  # dropless: what capacity keeps when it keeps all
+        roomy = dataclasses.replace(cfg, capacity_factor=8.0)
+        full = L._moe_dense(dict(p, **{w: jnp.pad(p[w], ((0, 6), (0, 0),
+                                                         (0, 0)))
+                                       for w in ("w_gate", "w_up",
+                                                 "w_down")}),
+                            dataclasses.replace(roomy, held_experts=None), x)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(full[0], np.float32),
+                                   atol=0.05, rtol=0.05)

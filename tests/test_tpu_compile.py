@@ -14,6 +14,7 @@ different tests.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -161,12 +162,11 @@ def test_layer_grad_holds_no_score_square(one_chip, compiled_kernels):
     assert f"{H},{L_CELL},{L_CELL}]" not in text
 
 
-def test_mellum2_period_compiles_with_its_kernels(one_chip, compiled_kernels):
-    """One period of Mellum2 (three 1024-token window layers, one full
-    layer with YaRN) at its published widths, 8 of its 64 experts held,
-    loss and gradients in bf16 over 1 × 2048 tokens: the plain and the
-    window flash kernels and the grouped matmuls are in the program, and
-    no (B, H, L, L) buffer is."""
+def _mellum2_period_grad(one_chip, **changes):
+    """The compiled text of the gradient of the loss over one period of
+    Mellum2 (three 1024-token window layers, one full layer with YaRN) at
+    its published widths, 8 of its 64 experts held, in bf16 over 1 ×
+    2048 tokens, remat on; ``changes`` to the configuration."""
     import dataclasses
 
     from repro.configs import get_config
@@ -175,7 +175,8 @@ def test_mellum2_period_compiles_with_its_kernels(one_chip, compiled_kernels):
 
     cfg = dataclasses.replace(
         get_config("mellum2-12b-a2.5b").with_depth(4).with_expert_share(8),
-        vocab_size=1024, param_dtype="bfloat16", compute_dtype="bfloat16")
+        vocab_size=1024, param_dtype="bfloat16", compute_dtype="bfloat16",
+        **changes)
     params = jax.eval_shape(lambda: T.init_model(jax.random.PRNGKey(0), cfg))
     params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), params)
     toks = _sds((1, L_CELL), jnp.int32, one_chip)
@@ -184,7 +185,37 @@ def test_mellum2_period_compiles_with_its_kernels(one_chip, compiled_kernels):
         logits, aux = T.forward(p, cfg, tokens=t, remat=True)
         return lm_loss(logits, t, aux)
 
-    text = _compile(jax.grad(loss), params, toks)
+    return _compile(jax.grad(loss), params, toks)
+
+
+def _branches(comps):
+    """The branch computations of every conditional."""
+    return [c for instrs in comps.values() for i in instrs.values()
+            if i["opcode"] == "conditional"
+            for kind, c in i["callees"] if kind == "branch"]
+
+
+def _reach(comps, comp):
+    """``comp`` and every computation it calls, transitively."""
+    out, todo = set(), [comp]
+    while todo:
+        c = todo.pop()
+        if c in comps and c not in out:
+            out.add(c)
+            todo += [callee for i in comps[c].values()
+                     for _, callee in i["callees"]]
+    return out
+
+
+def _scopes(op_name):  # jvp(scope), transpose(jvp(scope)) name it too
+    return set(re.split(r"[/()]", op_name))
+
+
+def test_mellum2_period_compiles_with_its_kernels(one_chip, compiled_kernels):
+    """One period of Mellum2 (``_mellum2_period_grad``): the plain and the
+    window flash kernels and the grouped matmuls are in the program, and
+    no (B, H, L, L) buffer is."""
+    text = _mellum2_period_grad(one_chip)
     kernels = {line.split(" = ")[0].strip().lstrip("%").split(".")[0]
                for line in text.splitlines() if "tpu_custom_call" in line}
     assert {"flash_attention_fwd", "flash_attention_dq",
@@ -192,3 +223,91 @@ def test_mellum2_period_compiles_with_its_kernels(one_chip, compiled_kernels):
             "flash_attention_window_dq", "flash_attention_window_dkv",
             "expert_gmm", "expert_tgmm"} <= kernels, kernels
     assert f"32,{L_CELL},{L_CELL}]" not in text
+
+
+def test_expert_layer_moves_only_the_held_rows(one_chip, compiled_kernels):
+    """Mellum2's expert layer at its published widths, 8 of 64 experts
+    held, one 8192-token sequence (T·k = 65,536 assignments, 16,384 rows
+    kept), loss and gradients under remat in bf16.  Outside the spill
+    branch, which carries every assignment, no (T·k, F) buffer is left,
+    and the only (T·k, D) buffers are the combine's gather in the forward
+    and the dispatch gradient's gather in the backward (the TPU's gathers
+    do not fuse into the sum over k that reads them): no branch keeps a
+    T·k-row residual for the other."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.core.scopes import _parse
+    from repro.models import layers as L
+
+    cfg = dataclasses.replace(
+        get_config("mellum2-12b-a2.5b").with_expert_share(8),
+        param_dtype="bfloat16", compute_dtype="bfloat16", frozen_router=True)
+    t, d, f = 8192, cfg.d_model, cfg.expert_d_ff
+    r = t * cfg.top_k
+    assert L._compact_rows(r, 8, cfg.num_experts) == 16384
+    params = jax.eval_shape(lambda: L.init_moe(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), params)
+    x = _sds((1, t, d), jnp.bfloat16, one_chip)
+
+    @jax.checkpoint
+    def loss(p, x):
+        out, _ = L.moe(p, cfg, x)
+        return jnp.sum(out.astype(jnp.float32) * jnp.cos(out.shape[-1]))
+
+    comps, _ = _parse(_compile(jax.value_and_grad(loss, argnums=(0, 1)),
+                               params, x))
+    fused = {c for instrs in comps.values() for i in instrs.values()
+             for kind, c in i["callees"]
+             if kind in ("calls", "to_apply") and i["opcode"] != "call"}
+
+    branches = _branches(comps)
+    spill = set().union(*(
+        _reach(comps, b) for b in branches
+        if any("moe.spill" in _scopes(i["op"]) for c in _reach(comps, b)
+               for i in comps[c].values())))
+    assert len(branches) == 4 and spill  # forward and backward, two each
+    rows = {}
+    for c, instrs in comps.items():
+        if c in fused or c in spill:
+            continue
+        for name, i in instrs.items():
+            if i["opcode"] in ("parameter", "get-tuple-element", "tuple",
+                               "bitcast", "conditional"):  # its branches'
+                continue
+            for width in (d, f):
+                if f"[{r},{width}]" in i["type"] or \
+                        f"[{t},{cfg.top_k},{width}]" in i["type"]:
+                    rows.setdefault(width, []).append(i["op"])
+    assert f not in rows, rows[f]
+    assert len(rows[d]) == 2, rows[d]
+    combine, dispatch_grad = sorted(rows[d], key=lambda op: "transpose(" in op)
+    assert combine.endswith("moe.compact/gather"), combine
+    assert "transpose(jvp(moe.compact))" in dispatch_grad and \
+        dispatch_grad.endswith("/gather"), dispatch_grad
+
+
+def test_expert_paths_keep_their_ops_in_their_branches(one_chip,
+                                                       compiled_kernels):
+    """One period of Mellum2 as its cell trains it (``_mellum2_period_grad``,
+    the router frozen; 2048 tokens top-8, 4096 of the 16,384 assignments
+    kept): every op under ``moe.compact`` or ``moe.spill``, forward and
+    backward, stays inside a branch of the layer's choice.  XLA hoists the
+    ops that end both branches alike out of a conditional, under one
+    branch's scope, unless the branches' results pass a barrier; a trace
+    would then count them as that branch's work whichever branch ran
+    (``moe_compact_share.train``)."""
+    from repro.core.scopes import _parse
+
+    comps, _ = _parse(_mellum2_period_grad(one_chip, frozen_router=True))
+    branches = _branches(comps)
+    assert len(branches) == 16  # 4 layers, forward and backward, two each
+    inside = set().union(*(_reach(comps, b) for b in branches))
+    outside = [i["op"] for c, instrs in comps.items() if c not in inside
+               for i in instrs.values()
+               if {"moe.compact", "moe.spill"} & _scopes(i["op"])]
+    assert not outside, outside
+    for b in branches:
+        ops = {scope for c in _reach(comps, b) for i in comps[c].values()
+               for scope in {"moe.compact", "moe.spill"} & _scopes(i["op"])}
+        assert len(ops) == 1, (b, ops)
